@@ -32,6 +32,7 @@ from repro.kernels import (
     geometric_median,
     krum,
 )
+from repro.kernels.krum import row_tile
 from repro.kernels.ref import (
     clip_then_aggregate_ref,
     clip_then_geometric_median_ref,
@@ -128,13 +129,15 @@ def traffic_model_krum_apply(n: int, d: int, itemsize: int = 4) -> dict:
     full:   the tile-wise weighted row-sum streams ALL n rows — required
             for multi-Krum weights and bucketed winner means.
     onehot: plain (unbucketed) Krum's combination is one-hot, so the
-            scalar-prefetch ``select_row`` kernel streams ONLY the winner
-            row's tiles — d bytes read instead of n*d, plus the (d,)
-            output either way.
+            scalar-prefetch ``select_row`` kernel streams ONLY the sublane
+            tile group holding the winner — ``row_tile`` rows (8 of f32,
+            16 of bf16, or all n when n is smaller) instead of n, plus
+            the (d,) output either way.
     """
     out = d * itemsize
     full = n * d * itemsize + out
-    onehot = d * itemsize + out
+    rows = row_tile(n, jnp.dtype(f"float{8 * itemsize}"))
+    onehot = rows * d * itemsize + out
     return {
         "n": n, "d": d,
         "full_bytes": full,
@@ -371,7 +374,8 @@ def run(quick: bool = False, out_json: str = BENCH_JSON):
         )
     )
     # plain Krum's one-hot apply: the scalar-prefetch select_row kernel
-    # streams only the winner row's tiles — d bytes instead of n*d
+    # streams only the sublane tile group holding the winner — row_tile*d
+    # elements instead of n*d
     tma = traffic_model_krum_apply(n, d)
     us_onehot = _time(
         jax.jit(select_row), xs, jnp.int32(3), jnp.float32(0.5)
@@ -456,7 +460,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.api import AggregatorSpec, ScheduleSpec, ServerPlan
-from repro.launch.mesh import make_debug_mesh, set_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.train import ByzTrainConfig, robust_aggregate
 
 d = int(sys.argv[1])
@@ -484,7 +488,7 @@ configs = [
     # perf gate exercises the pipelined path on every PR
     ("pipelined", plan_json("sharded", "pipelined", d // 4)),
 ]
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     tree = jax.device_put(tree, NamedSharding(mesh, P("data")))
     for sched, pj in configs:
         cfg = ByzTrainConfig.from_plan(ServerPlan.from_json(pj))

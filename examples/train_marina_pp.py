@@ -1,19 +1,25 @@
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-# ^ before any jax import: this demo runs the REAL distributed trainer on 8
-#   faked CPU devices — mesh (data=4, model=2): 4 workers, one byzantine.
+# ^ before any jax import: on the CPU this demo runs the REAL distributed
+#   trainer on 8 faked devices — mesh (data=4, model=2): 4 workers, one
+#   byzantine.  On accelerators the flag does nothing and the mesh is built
+#   from the devices present.
 
 """End-to-end driver: train a ~100M-parameter transformer with
 Byz-VR-MARINA-PP on the distributed mesh trainer for a few hundred steps.
 
 This exercises the FULL production path: the same make_train_step /
 sharding rules / robust-aggregation collective schedule that the 256-chip
-dry-run lowers — on a small (4 workers x 2-way TP) CPU mesh, with one
-bit-flipping byzantine worker, trained on the synthetic token pipeline.
+dry-run lowers — on a mesh over the devices present (2-way tensor
+parallel from 8 devices up, the rest workers), with one bit-flipping
+byzantine worker, trained on the synthetic token pipeline.
 
     PYTHONPATH=src python examples/train_marina_pp.py --steps 200
     PYTHONPATH=src python examples/train_marina_pp.py --steps 8 --smoke
+    # one chip: four workers share it (naive placement)
+    PYTHONPATH=src python examples/train_marina_pp.py --workers 4 \
+        --agg-schedule naive
 """
 import argparse
 import time
@@ -25,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save
 from repro.data.pipeline import make_batch_iterator
 from repro.launch.cli import add_plan_args, plan_from_args
-from repro.launch.mesh import make_debug_mesh, num_workers, set_mesh
+from repro.launch.mesh import make_local_mesh, num_workers
 from repro.launch.train import (
     ByzTrainConfig,
     MeshTrainState,
@@ -57,6 +63,9 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--n-byz", type=int, default=1)
+    ap.add_argument("--workers", type=int, default=0,
+                    help="number of workers (0: one per device of the worker "
+                         "axis; more than that needs --agg-schedule naive)")
     ap.add_argument("--ckpt-dir", default="")
     # The full server-step composition comes from the shared ServerPlan
     # flag group (repro.launch.cli): --aggregator/--agg-schedule/
@@ -68,8 +77,8 @@ def main():
     args = ap.parse_args()
 
     cfg = build_config(args.smoke)
-    mesh = make_debug_mesh(data=4, model=2)
-    W = num_workers(mesh)
+    mesh = make_local_mesh(model=2 if len(jax.devices()) >= 8 else 1)
+    W = args.workers or num_workers(mesh)
     print(f"model {cfg.name}: {param_count(cfg)/1e6:.1f}M params; "
           f"{W} workers ({args.n_byz} byzantine), mesh {dict(mesh.shape)}")
 
@@ -80,11 +89,12 @@ def main():
         p=0.125,
         n_byz=args.n_byz,
         attack="bf",
+        n_workers=W,
     )
     step_fn = make_train_step(cfg, mesh, tc)
 
     it = make_batch_iterator(cfg, W * args.per_worker_batch, args.seq)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(jax.random.PRNGKey(0), cfg)
         batch0 = next(it)
         g0 = jax.grad(lambda p: apply_train(p, cfg, batch0)[0])(params)

@@ -9,7 +9,8 @@ point; the semantics (and the bitwise guarantees pinned by
 tests/test_mesh_trainer.py and tests/test_superleaf.py) are unchanged:
 
   naive    — the paper's parameter-server semantics: gather every worker's
-             message (XLA all-gathers the worker dim), aggregate everywhere.
+             message (XLA all-gathers the worker dim), aggregate everywhere;
+             each chip keeps its tensor-parallel shard of every row.
              Collective bytes per chip ~ W * |shard|.
   sharded  — beyond-paper scatter-aggregate-gather: all_to_all the worker
              messages so each chip owns all W values for 1/W-th of its
@@ -54,20 +55,24 @@ from .plan import PlanError, ScheduleSpec
 __all__ = [
     "run_mesh_aggregate",
     "leaf_agg_of",
+    "mesh_worker_axes",
     "mesh_worker_count",
     "schedule_map",
-    "shard_map_compat",
 ]
 
 F32 = jnp.float32
 _BIG = F32(3.4e37)
 
 
+def mesh_worker_axes(mesh, worker_axes_override: tuple = ()) -> tuple:
+    """The mesh axes the plan's workers enumerate over."""
+    return tuple(worker_axes_override) or _default_worker_axes(mesh)
+
+
 def mesh_worker_count(mesh, worker_axes_override: tuple = ()) -> int:
     """Number of workers the plan's worker axes enumerate on ``mesh``."""
-    waxes = tuple(worker_axes_override) or _default_worker_axes(mesh)
     W = 1
-    for a in waxes:
+    for a in mesh_worker_axes(mesh, worker_axes_override):
         W *= mesh.shape[a]
     return W
 
@@ -148,22 +153,50 @@ def schedule_map(produce, consume, n, pipelined: bool):
     return outs
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names):
-    """jax.shard_map on jax >= 0.5; jax.experimental.shard_map before.
+def _reduce_over(axes: tuple):
+    """psum over ``axes`` as a ``reduce_fn`` (None when no axis shards
+    the block: the row statistics are already global)."""
+    return _psum_reduce(axes) if axes else None
 
-    The legacy API has no ``axis_names`` — every mesh axis is manual, which
-    matches the callers here (``axis_names`` always covers the whole mesh:
-    worker axes plus "model")."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=axis_names, check_vma=False,
+
+def _naive_aggregate(tree_w, mask, key, factors, *, agg, leaf_agg,
+                     two_phase, chunk_elems, use_factors, stat_axes):
+    """The naive placement's aggregation of a whole worker-stacked tree on
+    one chip: every worker's row of this chip's grad shard.  No
+    collectives to overlap: ``blocks`` is a no-op here, but superleaf
+    packing still applies (uniform per-chunk dispatch).  ``stat_axes``
+    (per flattened leaf) are the mesh axes its grad spec shards; a psum
+    over them gives the non-coordinate-wise rules their global row
+    statistics, as in the sharded placement."""
+    factors = factors if use_factors else None
+    if chunk_elems > 0:
+        blocks, block_axes, unpack = tree_superleaf_pack(
+            tree_w, chunk_elems, group_ids=stat_axes
         )
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
+    else:
+        leaves, treedef = jax.tree_util.tree_flatten(tree_w)
+        blocks = [l.reshape(l.shape[0], -1) for l in leaves]
+        block_axes = stat_axes
 
-    return legacy_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
+        def unpack(rows):
+            return jax.tree_util.tree_unflatten(
+                treedef,
+                [r.reshape(l.shape[1:]) for r, l in zip(rows, leaves)],
+            )
+    if two_phase:
+        stats = None
+        for b, axes in zip(blocks, block_axes):
+            g = agg.accumulate_stats(b, reduce_fn=_reduce_over(axes))
+            stats = g if stats is None else stats + g
+        sel = agg.finalize(stats, mask=mask, key=key, factors=factors)
+        rows = agg.apply_selection(blocks, sel)
+    else:
+        rows = [
+            leaf_agg(b, mask, key, factors=factors,
+                     reduce_fn=_reduce_over(axes))
+            for b, axes in zip(blocks, block_axes)
+        ]
+    return unpack(rows)
 
 
 def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
@@ -188,10 +221,8 @@ def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
     two_phase = agg.supports_two_phase
     pipelined = spec.blocks == "pipelined"
     chunk_elems = int(spec.superleaf_elems)
-    waxes = tuple(spec.worker_axes) or _default_worker_axes(mesh)
-    W = 1
-    for a in waxes:
-        W *= mesh.shape[a]
+    waxes = mesh_worker_axes(mesh, spec.worker_axes)
+    W = mesh_worker_count(mesh, spec.worker_axes)
 
     n_rows = jax.tree_util.tree_leaves(tree_w)[0].shape[0]
     use_factors = radius is not None
@@ -200,61 +231,58 @@ def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
     else:
         factors = jnp.ones((n_rows,), F32)
 
-    if spec.placement == "naive" or not waxes:
-        # no collectives to overlap: spec.blocks is a no-op here, but
-        # superleaf packing still applies (uniform per-chunk dispatch)
-        if chunk_elems > 0:
-            chunks, _, unpack = tree_superleaf_pack(tree_w, chunk_elems)
-            if two_phase:
-                stats = agg.accumulate_stats(chunks)
-                sel = agg.finalize(
-                    stats, mask=mask, key=key,
-                    factors=factors if use_factors else None,
-                )
-                rows = agg.apply_selection(chunks, sel)
-            else:
-                rows = [
-                    leaf_agg(
-                        c, mask, key,
-                        factors=factors if use_factors else None,
-                    )
-                    for c in chunks
-                ]
-            return unpack(rows)
-        if two_phase:
-            leaves, treedef = jax.tree_util.tree_flatten(tree_w)
-            mats = [l.reshape(l.shape[0], -1) for l in leaves]
-            stats = agg.accumulate_stats(mats)
-            sel = agg.finalize(
-                stats, mask=mask, key=key,
-                factors=factors if use_factors else None,
-            )
-            outs = [
-                agg.apply_selection(mat, sel).reshape(l.shape[1:])
-                for mat, l in zip(mats, leaves)
-            ]
-            return jax.tree_util.tree_unflatten(treedef, outs)
-        return jax.tree_util.tree_map(
-            lambda l: leaf_agg(
-                l, mask, key, factors=factors if use_factors else None
-            ),
-            tree_w,
+    if base_specs is None:
+        base_specs = jax.tree_util.tree_map(
+            lambda l: P(*([None] * (l.ndim - 1))), tree_w
         )
+    spec_leaves = jax.tree_util.tree_leaves(
+        base_specs, is_leaf=lambda x: isinstance(x, P)
+    )
+
+    if spec.placement == "naive" or not waxes:
+        if mesh.size == 1:
+            # nothing to partition; a shard_map would only cost eager
+            # callers a whole-program compile per call
+            return _naive_aggregate(
+                tree_w, mask, key, factors, agg=agg, leaf_agg=leaf_agg,
+                two_phase=two_phase, chunk_elems=chunk_elems,
+                use_factors=use_factors, stat_axes=[()] * len(spec_leaves),
+            )
+        # a manual shard_map, because a Pallas TPU kernel cannot be
+        # partitioned automatically: the worker dim is replicated (XLA
+        # all-gathers it), every other dim keeps its grad sharding, so
+        # each chip aggregates all W rows of its own shard
+        naive = partial(
+            _naive_aggregate, agg=agg, leaf_agg=leaf_agg,
+            two_phase=two_phase, chunk_elems=chunk_elems,
+            use_factors=use_factors,
+            stat_axes=[_spec_axes(sp) for sp in spec_leaves],
+        )
+        in_specs = jax.tree_util.tree_map(
+            lambda s: P(None, *s), base_specs,
+            is_leaf=lambda x: isinstance(x, P),
+        )
+        return jax.shard_map(
+            naive,
+            mesh=mesh,
+            in_specs=(in_specs, P(), P(), P()),
+            out_specs=base_specs,
+            axis_names=set(mesh.axis_names),
+            check_vma=False,
+        )(tree_w, mask, key, factors)
 
     if n_rows != W:
         # the sharded placement shards the worker axis over ``waxes``; a
         # row-count mismatch would silently drop (or duplicate) workers
         # in the per-chip scatter
         raise PlanError(
-            f"sharded robust aggregation needs one row per worker: leaves "
-            f"carry {n_rows} rows but the mesh enumerates {W} workers "
-            f"over {waxes}"
+            f"the sharded placement needs one row per worker and one worker "
+            f"per device: leaves "
+            f"carry {n_rows} worker rows but the mesh has {W} devices on "
+            f"its worker axes {waxes}; use placement='naive' to run "
+            "several workers per device"
         )
     wspec = waxes if len(waxes) > 1 else waxes[0]
-    if base_specs is None:
-        base_specs = jax.tree_util.tree_map(
-            lambda l: P(*([None] * (l.ndim - 1))), tree_w
-        )
     in_specs = jax.tree_util.tree_map(
         lambda s: P(wspec, *s), base_specs, is_leaf=lambda x: isinstance(x, P)
     )
@@ -275,9 +303,6 @@ def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
 
     def body(t, m, k, f):
         leaves, treedef = jax.tree_util.tree_flatten(t)
-        spec_leaves = jax.tree_util.tree_leaves(
-            base_specs, is_leaf=lambda x: isinstance(x, P)
-        )
         # Each block's coordinates are spread over the worker axes (the
         # all_to_all chunks) plus whatever axes its grad spec shards — a
         # psum over exactly those gives the non-coordinate-wise rules
@@ -306,13 +331,18 @@ def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
             flat = flats[i]  # chip-local: no hidden resharding
             if pads[i]:
                 flat = jnp.pad(flat, (0, pads[i]))
-            sw = flat.reshape(W, -1)
+            m = flat.shape[0] // W
+            # exchange (W, m/128, 128) blocks: the TPU compiler spends
+            # minutes on an all_to_all whose minor dim is a whole leaf
+            # (213 s vs 1 s for a 77M-element bf16 leaf on v5e:2x2)
+            lanes = 128 if m % 128 == 0 else m
+            sw = flat.reshape(W, m // lanes, lanes)
             for ax in waxes:  # all_to_all over each worker axis in turn
-                n_ax = mesh.shape[ax]  # static (axis_size needs >= 0.5)
-                sw = sw.reshape(n_ax, -1, sw.shape[-1])
+                n_ax = mesh.shape[ax]
+                sw = sw.reshape((n_ax, -1) + sw.shape[1:])
                 sw = jax.lax.all_to_all(sw, ax, split_axis=0, concat_axis=0)
-                sw = sw.reshape(-1, sw.shape[-1])
-            return sw
+                sw = sw.reshape((-1,) + sw.shape[2:])
+            return sw.reshape(W, m)
 
         def gather(aggd, i):
             out = aggd
@@ -366,11 +396,12 @@ def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
         outs = [r.reshape(shp) for r, shp in zip(rows, shapes)]
         return jax.tree_util.tree_unflatten(treedef, outs)
 
-    smapped = shard_map_compat(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(in_specs, P(), P(), P()),
         out_specs=base_specs,
         axis_names=all_axes,
+        check_vma=False,
     )
     return smapped(tree_w, mask, key, factors)

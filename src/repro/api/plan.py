@@ -402,11 +402,14 @@ class ServerPlan:
             kw["frac"] = c.frac
         return make_compressor(c.kind, **kw)
 
-    def build(self, mesh=None) -> "ServerStep":
+    def build(self, mesh=None, *, n_workers: Optional[int] = None
+              ) -> "ServerStep":
         """Compile the plan into one :class:`ServerStep` callable.
 
         ``mesh=None`` builds the whole-message engine form; a mesh builds
-        the distributed form under ``self.schedule``."""
+        the distributed form under ``self.schedule``.  ``n_workers`` is the
+        number of message rows when it differs from the mesh's worker
+        devices (several workers per device, naive placement)."""
         if mesh is None and self.schedule.placement == "sharded":
             raise PlanError(
                 "placement='sharded' needs a mesh: build(mesh) runs the "
@@ -414,11 +417,19 @@ class ServerPlan:
                 "placement='naive' for the single-process engine form"
             )
         if mesh is not None:
-            from .mesh_exec import mesh_worker_count
+            from .mesh_exec import mesh_worker_axes, mesh_worker_count
 
-            self.validate_workers(
-                mesh_worker_count(mesh, self.schedule.worker_axes)
-            )
+            waxes = mesh_worker_axes(mesh, self.schedule.worker_axes)
+            slots = mesh_worker_count(mesh, waxes)
+            if (self.schedule.placement == "sharded" and waxes
+                    and n_workers and n_workers != slots):
+                raise PlanError(
+                    f"the sharded placement needs one worker per device: "
+                    f"{n_workers} workers but the mesh has {slots} devices "
+                    "on its worker axes; use placement='naive' to run "
+                    "several workers per device"
+                )
+            self.validate_workers(n_workers or slots)
         return ServerStep(self, mesh=mesh)
 
     # -- introspection -------------------------------------------------------

@@ -48,7 +48,7 @@ selection algebra once on the total, and ``apply_selection`` applies the
 resulting row combination to each block (on the pallas backend: the
 tile-wise winner row-sum kernel, or — for plain unbucketed Krum, whose
 combination is one-hot — the scalar-prefetch single-row kernel that
-streams only the winner row).  Both phases also consume PACKED CHUNK
+streams only the sublane tile group holding the winner).  Both phases also consume PACKED CHUNK
 LISTS (``tree_utils.tree_superleaf_pack``): ``accumulate_stats`` of a
 list sums the chunks' Grams in order, ``apply_selection`` of a list
 returns the per-chunk outputs — the layout the pipelined mesh schedule
@@ -68,6 +68,7 @@ import jax.numpy as jnp
 
 from ..kernels import ops as _kops
 from ..kernels.krum import (
+    HIGHEST as _HIGHEST,
     RowSelection,
     krum_scores as _krum_scores,
     krum_select_from_gram as _krum_select_from_gram,
@@ -179,7 +180,7 @@ def _krum_scores_of(x32, mask_b, reduce_fn, byz_bound):
     across coordinate shards when ``reduce_fn`` is set) fed into the
     selection helpers shared with the pallas backend (repro.kernels.krum)
     — masking, neighbour count and tie-breaking live in ONE place."""
-    gram = x32 @ x32.T
+    gram = jnp.dot(x32, x32.T, precision=_HIGHEST)
     if reduce_fn is not None:
         gram = reduce_fn(gram)
         sq = jnp.diagonal(gram)  # global row ssq comes from the reduction
@@ -530,9 +531,11 @@ _FACTORY = {
 # ---------------------------------------------------------------------------
 
 def resolve_backend(backend: str) -> str:
-    """Resolve "auto" to the concrete backend for this process."""
+    """Resolve "auto" to the concrete backend for this process: the
+    kernels on the TPU, the jnp reference on the CPU (an error on any
+    other platform)."""
     if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "pallas" if _kops.kernel_platform() == "tpu" else "jnp"
     if backend not in ("jnp", "pallas"):
         raise ValueError(
             f"unknown backend {backend!r}; have 'jnp', 'pallas', 'auto'"
@@ -606,16 +609,17 @@ def _krum_two_phase_fns(*, byz_bound, m_select, multi, bucket_s,
         stats_fn = _kops.krum_gram
         cross_fn = _kops.krum_cross_gram
         # plain unbucketed Krum's combination is one-hot: the apply pass
-        # streams only the winner row (scalar-prefetch select_row kernel)
+        # streams only the winner's sublane tile group (select_row)
         apply_fn = partial(_kops.krum_apply, onehot=onehot)
     else:
         def stats_fn(xs, reduce_fn=None):
             x32 = xs.astype(jnp.float32)
-            gram = x32 @ x32.T
+            gram = jnp.dot(x32, x32.T, precision=_HIGHEST)
             return reduce_fn(gram) if reduce_fn is not None else gram
 
         def cross_fn(a, b):
-            return a.astype(jnp.float32) @ b.astype(jnp.float32).T
+            return jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32).T,
+                           precision=_HIGHEST)
 
         def apply_fn(xs, sel):
             x32 = xs.astype(jnp.float32)

@@ -20,6 +20,25 @@ from repro.models.model import ModelConfig
 __all__ = ["synthetic_batch", "TokenStream", "make_batch_iterator"]
 
 
+def token_runs(key, batch: int, seq: int, vocab: int, follow: float = 0.75):
+    """(batch, seq) int32 tokens with next-token structure: each token is,
+    with probability ``follow``, its predecessor plus one (mod ``vocab``),
+    else a fresh draw from a Zipf-ish marginal.  I.i.d. tokens would leave
+    a language model nothing to learn beyond that marginal (a fraction of
+    a nat), too little for a loss curve to show training; runs give it a
+    next-token rule worth several nats."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    fresh = jnp.minimum(
+        jax.random.randint(k1, (batch, seq), 0, vocab),
+        jax.random.randint(k2, (batch, seq), 0, vocab),
+    )
+    t = jnp.arange(seq)
+    restart = ~jax.random.bernoulli(k3, follow, (batch, seq)) | (t == 0)
+    start = jax.lax.cummax(jnp.where(restart, t, 0), axis=1)
+    first = jnp.take_along_axis(fresh, start, axis=1)
+    return (first + (t - start)) % vocab
+
+
 def synthetic_batch(key, cfg: ModelConfig, batch: int, seq: int) -> Dict:
     """One fabricated batch for the given architecture."""
     k1, k2, k3 = jax.random.split(key, 3)
@@ -29,13 +48,7 @@ def synthetic_batch(key, cfg: ModelConfig, batch: int, seq: int) -> Dict:
             "targets": jax.random.randint(k2, (batch, seq), 0, cfg.vocab),
             "mask": jax.random.bernoulli(k3, 0.65, (batch, seq)),
         }
-    out = {
-        # Zipf-ish marginal so the CE landscape is not flat-random
-        "tokens": jnp.minimum(
-            jax.random.randint(k1, (batch, seq), 0, cfg.vocab),
-            jax.random.randint(k2, (batch, seq), 0, cfg.vocab),
-        )
-    }
+    out = {"tokens": token_runs(k1, batch, seq, cfg.vocab)}
     if cfg.input_kind == "tokens+vision":
         out["vision"] = jax.random.normal(
             k3, (batch, cfg.n_vision_tokens, cfg.d_model), cfg.jdtype

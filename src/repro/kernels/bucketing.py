@@ -79,6 +79,7 @@ def bucketed_coordinate_median(
         out_specs=pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), xs.dtype),
         interpret=interpret,
+        name="bucketed_coordinate_median",
     )(perm, mask.reshape(n_p, 1), xp)
     out = out[0]
     return out[:d] if pad else out

@@ -5,14 +5,14 @@ CenteredClip (Karimireddy et al., 2021) iterates
 
 Two regimes, selected by VMEM footprint:
 
-  resident  (n_p + 2) * d fits the VMEM budget: the whole problem stays
-            in one block and all ``iters`` rounds run inside a single
-            kernel invocation.  The optional server clip (per-row factors
-            from the shared pass-1 row-norm accumulator in
-            clip_aggregate.py) and Bucketing (resident ``bucket_idx``
-            row-gather + mask-weighted bucket means) are applied
-            in-register before the iteration — the clipped matrix never
-            exists in HBM.
+  resident  ``resident_elems(n_p, d)`` fits the VMEM budget: the
+            whole problem stays in one block and all ``iters`` rounds
+            run inside a single kernel invocation.  The optional
+            server clip (per-row factors from the shared pass-1
+            row-norm accumulator in clip_aggregate.py) and Bucketing
+            (resident ``bucket_idx`` row-gather + mask-weighted bucket
+            means) are applied in-register before the iteration — the
+            clipped matrix never exists in HBM.
   tiled     larger d streams (n, TILE_D) blocks with a cross-tile norm
             reduction: each round runs one grid pass accumulating per-row
             partial sums of squares of (x*f - v), a host-side O(n) sqrt /
@@ -35,10 +35,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .clip_aggregate import _row_norms, clip_factor
-from .coordinate_median import TILE_D, _pad_to
+from .coordinate_median import (TILE_D, _pad_to, store_tile_partial,
+                                tile_partials)
 
 F32 = jnp.float32
-MAX_VMEM_ELEMS = 1 << 20  # (n_p + 2) * d floats must stay under ~4 MB
+MAX_VMEM_ELEMS = 1 << 20  # resident_elems(n_p, d) floats: ~4 MB of VMEM
+
+
+def resident_elems(n_p, d):
+    """VMEM floats the resident kernel holds for an (n_p, d) problem: the
+    rows fill whole 8-row sublane tiles, and each (1, d) iterate or
+    temporary fills a tile of its own (two tiles' worth)."""
+    return (-(-n_p // 8) * 8 + 16) * d
 
 
 # ---------------------------------------------------------------------------
@@ -120,7 +128,7 @@ def _cclip_resident_kernel(idx_ref, f_ref, m_ref, x_ref, o_ref, *, s, tau,
     o_ref[...] = v.astype(o_ref.dtype)
 
 
-def _run_resident(kernel, xs, mask_f, factors, bucket_idx, interpret):
+def _run_resident(kernel, xs, mask_f, factors, bucket_idx, interpret, name):
     n_p, d = xs.shape
     out = pl.pallas_call(
         kernel,
@@ -133,6 +141,7 @@ def _run_resident(kernel, xs, mask_f, factors, bucket_idx, interpret):
         out_specs=pl.BlockSpec((1, d), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, d), xs.dtype),
         interpret=interpret,
+        name=name,
     )(
         bucket_idx.reshape(n_p, 1),
         factors.reshape(n_p, 1).astype(F32),
@@ -150,7 +159,7 @@ def _diff_ssq_kernel(f_ref, z_ref, x_ref, o_ref):
     x = x_ref[...].astype(F32) * f_ref[...].astype(F32)  # (n, td)
     z = z_ref[...].astype(F32)  # (1, td)
     diff = x - z
-    o_ref[...] = jnp.sum(diff * diff, axis=1, keepdims=True)
+    store_tile_partial(o_ref, jnp.sum(diff * diff, axis=1, keepdims=True))
 
 
 def _cclip_update_kernel(den_ref, s_ref, f_ref, z_ref, x_ref, o_ref):
@@ -178,6 +187,7 @@ def diff_row_ssq(xp, z, factors, *, interpret, reduce_fn=None):
     to the full-vector semantics."""
     n, dp = xp.shape
     grid = dp // TILE_D
+    out_spec, out_shape = tile_partials(n, grid)
     partial = pl.pallas_call(
         _diff_ssq_kernel,
         grid=(grid,),
@@ -186,11 +196,12 @@ def diff_row_ssq(xp, z, factors, *, interpret, reduce_fn=None):
             pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
             pl.BlockSpec((n, TILE_D), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((n, 1), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, grid), F32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
+        name="diff_row_ssq",
     )(factors.reshape(n, 1), z, xp)
-    ssq = jnp.sum(partial, axis=1)
+    ssq = jnp.sum(partial[:, :grid], axis=1)
     return ssq if reduce_fn is None else reduce_fn(ssq)
 
 
@@ -212,6 +223,7 @@ def bucket_means_tiled(xp, mask_f, factors, bucket_idx, s, *, interpret):
         out_specs=pl.BlockSpec((nb, TILE_D), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((nb, dp), F32),
         interpret=interpret,
+        name="bucket_means",
     )(
         bucket_idx.reshape(n_p, 1),
         factors.reshape(n_p, 1).astype(F32),
@@ -251,6 +263,7 @@ def _cclip_tiled(xp, mask_f, factors, *, tau, iters, interpret,
             out_specs=pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((1, dp), F32),
             interpret=interpret,
+            name="centered_clip_update",
         )(den, scale, f_col, v, xp)
     return v[0]
 
@@ -261,7 +274,7 @@ def _cclip_tiled(xp, mask_f, factors, *, tau, iters, interpret,
 
 def run_clip_then_iterative(
     xs, radius, mask, bucket_idx, factors, *, bucket_s, use_clip,
-    reduce_fn, interpret, resident_kernel, tiled_fn,
+    reduce_fn, interpret, resident_kernel, tiled_fn, name,
 ):
     """Shared driver for the fused clip -> (Bucketing) -> iterative
     aggregation kernels (CenteredClip here, Weiszfeld GM in
@@ -271,7 +284,8 @@ def run_clip_then_iterative(
 
     ``resident_kernel(s)`` -> the whole-problem VMEM kernel for bucket
     size ``s``; ``tiled_fn(xp, mask_f, factors, reduce_fn)`` -> the
-    (1, dp) iterate of the streaming schedule.  ``factors`` (n,) skips
+    (1, dp) iterate of the streaming schedule; ``name`` names the
+    resident kernel.  ``factors`` (n,) skips
     the norm pass (precomputed per-row scales, e.g. the sharded
     trainer's global tree-norm factors); ``use_clip=False`` is the plain
     aggregation.  ``reduce_fn`` reduces every per-row sum-of-squares
@@ -301,9 +315,10 @@ def run_clip_then_iterative(
     n_p = xs_p.shape[0]
     s = bucket_s if bucket_s >= 2 else 1
 
-    if reduce_fn is None and (n_p + 2) * d <= MAX_VMEM_ELEMS:
+    if reduce_fn is None and resident_elems(n_p, d) <= MAX_VMEM_ELEMS:
         out = _run_resident(
-            resident_kernel(s), xs_p, mask_f, factors, bucket_idx, interpret
+            resident_kernel(s), xs_p, mask_f, factors, bucket_idx, interpret,
+            name,
         )
         return out, norms
 
@@ -351,6 +366,7 @@ def clip_then_centered_clip(
         resident_kernel=lambda s: functools.partial(
             _cclip_resident_kernel, s=s, tau=tau, iters=iters
         ),
+        name="centered_clip_resident",
         tiled_fn=lambda xp, m, f, rfn: _cclip_tiled(
             xp, m, f, tau=tau, iters=iters, interpret=interpret,
             reduce_fn=rfn,

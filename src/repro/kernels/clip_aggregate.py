@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .coordinate_median import TILE_D, _pad_to, _select_masked
+from .coordinate_median import (TILE_D, _pad_to, _select_masked,
+                                store_tile_partial, tile_partials)
 
 F32 = jnp.float32
 _BIG = 3.4e37
@@ -52,7 +53,7 @@ def clip_factor(norm, radius):
 
 def _rownorm_kernel(x_ref, o_ref):
     x = x_ref[...].astype(F32)  # (n, td)
-    o_ref[...] = jnp.sum(x * x, axis=1, keepdims=True)  # (n, 1)
+    store_tile_partial(o_ref, jnp.sum(x * x, axis=1, keepdims=True))
 
 
 def _clip_agg_kernel(factor_ref, mask_ref, x_ref, o_ref, *, trim_ratio):
@@ -89,15 +90,17 @@ def _row_norms(xp, grid, n, interpret, reduce_fn=None):
     """Per-row l2 norms via tile-partial sums of squares.  ``reduce_fn``
     (e.g. a psum over shard_map axes) turns block-local partial sums into
     global ones when ``xp`` is one coordinate shard of a larger row."""
+    out_spec, out_shape = tile_partials(n, grid)
     partial_ssq = pl.pallas_call(
         _rownorm_kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec((n, TILE_D), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((n, 1), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, grid), F32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
+        name="clip_row_norms",
     )(xp)
-    ssq = jnp.sum(partial_ssq, axis=1)  # (n,)
+    ssq = jnp.sum(partial_ssq[:, :grid], axis=1)  # (n,)
     if reduce_fn is not None:
         ssq = reduce_fn(ssq)
     return jnp.sqrt(ssq)
@@ -178,6 +181,7 @@ def clip_then_aggregate(
         kernel = functools.partial(
             _clip_bucket_agg_kernel, s=bucket_s, trim_ratio=trim_ratio
         )
+        name = "clip_bucket_aggregate"
         in_specs = [
             pl.BlockSpec((n_p, 1), lambda i: (0, 0)),  # idx: resident
             pl.BlockSpec((n_p, 1), lambda i: (0, 0)),  # factors: resident
@@ -192,6 +196,7 @@ def clip_then_aggregate(
         )
     else:
         kernel = functools.partial(_clip_agg_kernel, trim_ratio=trim_ratio)
+        name = "clip_aggregate"
         in_specs = [
             pl.BlockSpec((n, 1), lambda i: (0, 0)),  # factors: resident
             pl.BlockSpec((n, 1), lambda i: (0, 0)),  # mask: resident
@@ -206,6 +211,7 @@ def clip_then_aggregate(
         out_specs=pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), xs.dtype),
         interpret=interpret,
+        name=name,
     )(*operands)
     out = out[0]
     return (out[:d] if pad else out), norms

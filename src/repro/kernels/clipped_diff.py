@@ -18,6 +18,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .coordinate_median import store_tile_partial, tile_partials
 
 F32 = jnp.float32
 SUB = 8
@@ -32,7 +35,7 @@ def _diff_kernel(gn_ref, go_ref, keep_ref, scale_ref, d_ref, ssq_ref):
     scale = scale_ref[0]
     d = (gn - go) * keep * scale
     d_ref[...] = d.astype(d_ref.dtype)
-    ssq_ref[0, 0] = jnp.sum(d * d)
+    store_tile_partial(ssq_ref, jnp.sum(d * d).reshape(1, 1))
 
 
 def _scale_kernel(d_ref, f_ref, o_ref):
@@ -61,6 +64,7 @@ def clipped_diff(g_new, g_old, radius, keep_mask, scale, *, interpret: bool = Fa
     km, _ = _pad_flat(keep_mask.astype(g_new.dtype))
     grid = gn.shape[0]
     scale_arr = jnp.full((1,), scale, F32)
+    ssq_spec, ssq_shape = tile_partials(1, grid)
 
     d_masked, ssq = pl.pallas_call(
         _diff_kernel,
@@ -69,20 +73,21 @@ def clipped_diff(g_new, g_old, radius, keep_mask, scale, *, interpret: bool = Fa
             pl.BlockSpec((1, SUB, TILE), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, SUB, TILE), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, SUB, TILE), lambda i: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, SUB, TILE), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            ssq_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct(gn.shape, dtype),
-            jax.ShapeDtypeStruct((grid, 1), F32),
+            ssq_shape,
         ],
         interpret=interpret,
+        name="clipped_diff",
     )(gn, go, km, scale_arr)
 
-    norm = jnp.sqrt(jnp.sum(ssq))
+    norm = jnp.sqrt(jnp.sum(ssq[0, :grid]))
     factor = jnp.minimum(1.0, radius / jnp.maximum(norm, 1e-30)).astype(F32)
 
     out = pl.pallas_call(
@@ -90,11 +95,12 @@ def clipped_diff(g_new, g_old, radius, keep_mask, scale, *, interpret: bool = Fa
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((1, SUB, TILE), lambda i: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, SUB, TILE), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(gn.shape, dtype),
         interpret=interpret,
+        name="clipped_diff_scale",
     )(d_masked, factor.reshape(1))
 
     flat = out.reshape(-1)

@@ -23,6 +23,33 @@ from jax.experimental import pallas as pl
 F32 = jnp.float32
 _BIG = 3.4e37
 TILE_D = 512  # lanes: 512 = 4 * 128; sublanes: n (padded to 8)
+LANES = 128
+
+
+def tile_partials(rows, grid):
+    """Lane-dense layout for one partial per row per grid step.
+
+    A ``(rows, 1)`` block per step is not a legal TPU block (its lane dim
+    is neither a multiple of 128 nor the array's).  Instead step ``i``
+    writes lane ``i % 128`` of the resident ``(rows, 128)`` block
+    ``i // 128`` (``store_tile_partial``); the caller slices the first
+    ``grid`` columns back out, so the partials and their reduction order
+    are exactly those of a ``(rows, grid)`` array.  The grid axis must run
+    in order (the default "arbitrary" semantics).  Returns the kernel's
+    ``(out_spec, out_shape)``."""
+    cols = -(-grid // LANES) * LANES
+    return (
+        pl.BlockSpec((rows, LANES), lambda i, *_: (0, i // LANES)),
+        jax.ShapeDtypeStruct((rows, cols), F32),
+    )
+
+
+def store_tile_partial(o_ref, col):
+    """Write this grid step's ``(rows, 1)`` partials into its lane of the
+    ``tile_partials`` block; the other lanes keep what earlier steps
+    wrote."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+    o_ref[...] = jnp.where(lane == pl.program_id(0) % LANES, col, o_ref[...])
 
 
 def _ranks(vals, n):
@@ -115,6 +142,7 @@ def coordinate_median(xs, mask=None, *, trim_ratio: float = -1.0, interpret: boo
         out_specs=pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), xs.dtype),
         interpret=interpret,
+        name="coordinate_median" if trim_ratio < 0 else "trimmed_mean",
     )(mask, xp)
     out = out[0]
     return out[:d] if pad else out

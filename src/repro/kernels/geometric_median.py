@@ -90,6 +90,7 @@ def _gm_tiled(xp, mask_f, factors, *, iters, eps, interpret,
             out_specs=pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((1, dp), F32),
             interpret=interpret,
+            name="geometric_median_update",
         )(wsum, w, f_col, xp)
     return z[0]
 
@@ -126,6 +127,7 @@ def clip_then_geometric_median(
         resident_kernel=lambda s: functools.partial(
             _gm_resident_kernel, s=s, iters=iters, eps=eps
         ),
+        name="geometric_median_resident",
         tiled_fn=lambda xp, m, f, rfn: _gm_tiled(
             xp, m, f, iters=iters, eps=eps, interpret=interpret,
             reduce_fn=rfn,

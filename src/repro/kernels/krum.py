@@ -59,6 +59,11 @@ from .coordinate_median import TILE_D, _pad_to
 
 F32 = jnp.float32
 _BIG = 3.4e37
+# Every f32 matmul of the selection runs at full f32 precision: Krum's
+# distances are ||x_i||^2 + ||x_j||^2 - 2 <x_i, x_j>, a difference of
+# near-equal large terms, and the TPU's default f32 matmul (bf16 passes)
+# leaves errors larger than the distances between close rows.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +116,8 @@ def multi_krum_selection(scores, mask_b, byz_bound: Optional[int],
 def _gram_kernel(x_ref, o_ref):
     i = pl.program_id(0)
     x = x_ref[...].astype(F32)  # (n, td)
-    g = jnp.dot(x, x.T, preferred_element_type=F32)  # MXU (n, n)
+    g = jnp.dot(x, x.T, preferred_element_type=F32,
+                precision=HIGHEST)  # MXU (n, n)
 
     @pl.when(i == 0)
     def _init():
@@ -134,6 +140,7 @@ def gram_matrix(xs, *, interpret: bool = False):
         out_specs=pl.BlockSpec((n, n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n), F32),
         interpret=interpret,
+        name="krum_gram",
     )(xp)
 
 
@@ -141,7 +148,8 @@ def _cross_gram_kernel(a_ref, b_ref, o_ref):
     i = pl.program_id(0)
     a = a_ref[...].astype(F32)  # (n, td)
     b = b_ref[...].astype(F32)  # (n, td)
-    g = jnp.dot(a, b.T, preferred_element_type=F32)  # MXU (n, n)
+    g = jnp.dot(a, b.T, preferred_element_type=F32,
+                precision=HIGHEST)  # MXU (n, n)
 
     @pl.when(i == 0)
     def _init():
@@ -175,6 +183,7 @@ def cross_gram(a, b, *, interpret: bool = False):
         out_specs=pl.BlockSpec((n, n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n), F32),
         interpret=interpret,
+        name="krum_cross_gram",
     )(ap, bp)
 
 
@@ -213,6 +222,7 @@ def weighted_row_sum(xs, w_row, *, interpret: bool = False):
         out_specs=pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, xp.shape[1]), F32),
         interpret=interpret,
+        name="weighted_row_sum",
     )(w_row.astype(F32).reshape(n, 1), xp)
     out = out[0]
     return out[: xs.shape[1]] if pad else out
@@ -223,37 +233,52 @@ def weighted_row_sum(xs, w_row, *, interpret: bool = False):
 # ---------------------------------------------------------------------------
 
 def _select_row_kernel(row_ref, scale_ref, x_ref, o_ref):
-    # x_ref's block is (1, TILE_D): the index_map below uses the
-    # scalar-prefetched winner index as the ROW block coordinate, so the
-    # DMA engine only ever streams the winner row's tiles — d bytes
-    # instead of the n*d a full weighted_row_sum pass reads.
+    # x_ref's block is the (row_tile, TILE_D) sublane tile group holding
+    # the winner: the index_map below uses the scalar-prefetched winner
+    # index as the ROW block coordinate, so the DMA engine only streams
+    # that group's tiles, and the winner row is picked in-register (every
+    # other row contributes an exact 0, and its payload is never read).
     x = x_ref[...].astype(F32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    picked = rows == row_ref[0] % x.shape[0]
+    x = jnp.sum(jnp.where(picked, x, 0.0), axis=0, keepdims=True)
     s = scale_ref[0]
     # same non-finite guard as _row_combine_kernel: a zero clip factor
     # must produce exactly 0 even if a byzantine winner row carries inf
     o_ref[...] = jnp.where(s != 0.0, x * s, 0.0)
 
 
+def row_tile(n, dtype):
+    """Rows in the smallest row block a TPU can stream from an (n, d)
+    array: one sublane tile (8 rows of 32-bit words, 16 of bf16), or all
+    n rows when n is smaller.  HBM holds the array in (8, 128) tiles of
+    32-bit words, so a single row cannot be sliced out of it."""
+    return min(n, 8 * (4 // jnp.dtype(dtype).itemsize))
+
+
 def select_row(xs, winner, scale, *, interpret: bool = False):
-    """(n, d), () int32, () f32 -> (d,) f32: stream ONLY row ``winner``'s
-    tiles (scaled by ``scale``) via a scalar-prefetch index_map.
+    """(n, d), () int32, () f32 -> (d,) f32: stream ONLY the sublane tile
+    group holding row ``winner`` (``row_tile`` rows) via a
+    scalar-prefetch index_map, and return that row scaled by ``scale``.
 
     This is the plain (unbucketed) Krum apply pass: the selection is a
-    one-hot row combination, so streaming the other n-1 rows through
+    one-hot row combination, so streaming the other rows through
     ``weighted_row_sum`` just multiplies them by zero.  The winner index
-    is prefetched into SMEM before the grid runs and used as the row
-    block coordinate, cutting the apply pass from n*d to d streamed
-    bytes.  Bitwise-equal to the one-hot ``weighted_row_sum`` (both
-    compute x[winner] * scale in f32 with the same zero-factor guard).
+    is prefetched into SMEM before the grid runs and picks the row block,
+    cutting the apply pass from n*d to row_tile*d streamed elements.
+    Bitwise-equal to the one-hot ``weighted_row_sum`` (both compute
+    x[winner] * scale in f32 with the same zero-factor guard).
     """
     n = xs.shape[0]
+    rb = row_tile(n, xs.dtype)
     xp, pad = _pad_to(xs, TILE_D, axis=1)
     grid = xp.shape[1] // TILE_D
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(grid,),
         in_specs=[
-            pl.BlockSpec((1, TILE_D), lambda i, row, scale: (row[0], i)),
+            pl.BlockSpec((rb, TILE_D),
+                         lambda i, row, scale: (row[0] // rb, i)),
         ],
         out_specs=pl.BlockSpec((1, TILE_D), lambda i, row, scale: (0, i)),
     )
@@ -262,6 +287,7 @@ def select_row(xs, winner, scale, *, interpret: bool = False):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, xp.shape[1]), F32),
         interpret=interpret,
+        name="krum_select_row",
     )(
         jnp.clip(winner, 0, n - 1).astype(jnp.int32).reshape(1),
         scale.astype(F32).reshape(1),
@@ -362,7 +388,9 @@ def krum_select_from_gram(
         m_op, cnt = _bucket_operator(
             bucket_idx, mask_f, factors_p, n_p, bucket_s
         )
-        g_eff = m_op @ gram @ m_op.T  # Gram of clipped bucket means
+        # Gram of clipped bucket means
+        g_eff = jnp.dot(jnp.dot(m_op, gram, precision=HIGHEST), m_op.T,
+                        precision=HIGHEST)
         # the fp triple product is not exactly symmetric; Krum's
         # argmin-first tie-breaking on symmetric ties (mutual nearest
         # neighbours) needs d2[i,j] == d2[j,i] exactly
@@ -401,7 +429,7 @@ def krum_select_from_gram(
         w_row = w_sel * factors
     else:
         # selected-bucket means as one weighted row-sum over the raw rows
-        w_row = (w_sel @ m_op)[:n]
+        w_row = jnp.dot(w_sel, m_op, precision=HIGHEST)[:n]
     sel = RowSelection(
         weights=w_row, denom=denom,
         winner=jnp.argmin(scores).astype(jnp.int32),
@@ -419,8 +447,9 @@ def apply_row_selection(xs, selection: RowSelection, *,
     ``onehot=True`` (valid exactly when the selection is plain unbucketed
     Krum's one-hot combination — the caller knows this statically from
     ``multi``/``bucket_s``) takes the single-row fast path: the
-    scalar-prefetch ``select_row`` kernel streams only the winner row's
-    tiles, d bytes instead of n*d, with bitwise-identical output."""
+    scalar-prefetch ``select_row`` kernel streams only the sublane tile
+    group holding the winner, row_tile*d elements instead of n*d, with
+    bitwise-identical output."""
     if onehot:
         out = select_row(
             xs, selection.winner, selection.scale, interpret=interpret
@@ -479,7 +508,7 @@ def clip_then_krum(
         bucket_s=bucket_s, use_clip=use_clip,
     )
     # plain unbucketed Krum's combination is one-hot: stream only the
-    # winner row (d bytes) instead of all n rows
+    # winner's sublane tile group (row_tile rows) instead of all n rows
     out = apply_row_selection(
         xs, selection, onehot=selection_is_onehot(multi, bucket_s),
         interpret=interpret,

@@ -1,9 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On TPU the kernels run compiled; on CPU (this container) they execute in
-``interpret=True`` mode — the kernel bodies run in Python with identical
-semantics, which is what the allclose sweeps in tests/test_kernels.py rely
-on.  Callers never pass ``interpret`` themselves.
+On TPU the kernels run compiled; on CPU (the test platform) they execute
+in ``interpret=True`` mode — the kernel bodies run in Python with
+identical semantics, which is what the allclose sweeps in
+tests/test_kernels.py rely on.  Any other platform is an error: the
+kernels are written for the TPU, and interpreting them elsewhere would
+hide that they never ran compiled.  Callers never pass ``interpret``
+themselves.
 
 Backend contract (``repro.core.aggregators.make_aggregator(backend=...)``;
 the declarative entry point selecting it is
@@ -52,12 +55,13 @@ plan-level backend contract):
   Grams in order, ``krum_apply`` of a list applies the selection per
   chunk.  Plain (unbucketed) Krum's apply is a one-hot combination, so
   ``krum_apply(..., onehot=True)`` takes the scalar-prefetch
-  ``select_row`` kernel that streams ONLY the winner row's tiles — d
-  bytes instead of n*d.  ``clip_then_krum`` is that pipeline for a
+  ``select_row`` kernel that streams ONLY the sublane tile group holding
+  the winner — ``row_tile`` rows (8 f32 / 16 bf16, or all n when n is
+  smaller) instead of n.  ``clip_then_krum`` is that pipeline for a
   single matrix; winner reconstruction never gathers rows on the host.
-- ``backend="auto"``   — picks ``pallas`` iff ``jax.default_backend()`` is
-  TPU (where the tiling pays off), else ``jnp``.  On CPU the pallas choice
-  still *works* (interpret mode) and is what the equivalence tests use.
+- ``backend="auto"``   — picks ``pallas`` on the TPU and ``jnp`` on the
+  CPU; any other platform is an error.  On CPU the pallas choice still
+  *works* (interpret mode) and is what the equivalence tests use.
 
 The backend probe is memoized at module level: the default jax backend
 cannot change within a process, and ``jax.default_backend()`` initializes
@@ -120,10 +124,22 @@ __all__ = [
 _INTERPRET: Optional[bool] = None
 
 
+def kernel_platform() -> str:
+    """The default platform, which must be one the kernels support:
+    ``tpu`` (compiled) or ``cpu`` (interpret mode)."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels run compiled on 'tpu' or interpreted on "
+            f"'cpu'; the default platform is {platform!r}"
+        )
+    return platform
+
+
 def _interpret() -> bool:
     global _INTERPRET
     if _INTERPRET is None:
-        _INTERPRET = jax.default_backend() != "tpu"
+        _INTERPRET = kernel_platform() == "cpu"
     return _INTERPRET
 
 
@@ -354,9 +370,9 @@ def krum_apply(xs, selection, *, onehot: bool = False):
 
     ``onehot=True`` — valid exactly when the caller statically knows the
     selection is plain unbucketed Krum's one-hot combination
-    (``selection_is_onehot``) — streams only the winner row's tiles via
-    the scalar-prefetch ``select_row`` kernel (d bytes instead of n*d),
-    bitwise-equal to the full pass."""
+    (``selection_is_onehot``) — streams only the sublane tile group
+    holding the winner via the scalar-prefetch ``select_row`` kernel
+    (row_tile*d elements instead of n*d), bitwise-equal to the full pass."""
     return apply_selection_blocks(
         lambda block, sel: _apply_row_selection(
             block, sel, onehot=onehot, interpret=_interpret()
@@ -368,8 +384,9 @@ def krum_apply(xs, selection, *, onehot: bool = False):
 
 def select_row(xs, winner, scale):
     """(n, d), () int32, () f32 -> (d,) f32: the single-row fast path —
-    stream ONLY the winner row's tiles via a scalar-prefetch index_map
-    (d streamed bytes; ``weighted_row_sum`` of a one-hot reads n*d)."""
+    stream ONLY the sublane tile group holding the winner via a
+    scalar-prefetch index_map (row_tile*d streamed elements;
+    ``weighted_row_sum`` of a one-hot reads n*d)."""
     return _select_row(xs, winner, scale, interpret=_interpret())
 
 

@@ -40,7 +40,7 @@ from repro.api import (
 )
 from repro.configs.registry import get_config, get_smoke_config, list_archs
 from repro.configs.shapes import SHAPES, decode_variant, input_specs, mode_for
-from repro.launch.mesh import make_production_mesh, set_mesh, worker_axes
+from repro.launch.mesh import make_production_mesh, worker_axes
 from repro.launch.serve import make_prefill_step, make_serve_step
 from repro.launch.train import (
     ByzTrainConfig,
@@ -248,7 +248,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, smoke: bool = False,
     result["params"] = param_count(cfg)
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if mode == "train":
             state = abstract_state(cfg, train_cfg)
             sspecs = state_specs(mesh, cfg, state, train_cfg)

@@ -1,62 +1,52 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-Single pod:  (data=16, model=16)          — 256 chips (TPU v5e pod)
+Production:  (data=16, model=16)          — 256 chips (TPU v5e pod)
 Multi-pod:   (pod=2, data=16, model=16)   — 512 chips across 2 pods
+Local:       (data=#devices/model, model)  — whatever this host holds
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state.  The dry-run launcher sets XLA_FLAGS before any jax import to fake
-the device count; real deployments get the real topology.
+the production device count; the trainer CLI builds the local mesh from
+the devices present.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older releases default to Auto
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
     "make_debug_mesh",
-    "set_mesh",
+    "make_local_mesh",
     "worker_axes",
     "num_workers",
 ]
 
 
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on jax >= 0.5; on older releases a concrete Mesh is
-    itself the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+def _make_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return _make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):
-    """Small mesh for CPU subprocess tests (device count permitting)."""
+    """Small explicit mesh (device count permitting)."""
     if pod:
-        return jax.make_mesh(
-            (pod, data, model), ("pod", "data", "model"),
-            **_axis_type_kwargs(3),
-        )
-    return jax.make_mesh(
-        (data, model), ("data", "model"), **_axis_type_kwargs(2)
-    )
+        return _make_mesh((pod, data, model), ("pod", "data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
+
+
+def make_local_mesh(model: int = 1):
+    """(data, model) mesh over every device this process sees: ``model``
+    devices per tensor-parallel group, the rest on the worker axis."""
+    n = len(jax.devices())
+    if model < 1 or n % model:
+        raise ValueError(f"model={model} does not divide {n} devices")
+    return _make_mesh((n // model, model), ("data", "model"))
 
 
 def worker_axes(mesh) -> tuple:

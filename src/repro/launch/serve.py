@@ -454,6 +454,9 @@ def main():
     add_attack_args(ap, attack="gauss")  # stream mode's synthetic byz rows
     add_fault_args(ap)
     args = ap.parse_args()
+    from .cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.mode == "score":
         _main_score(args)
     elif args.mode == "stream":

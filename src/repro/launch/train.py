@@ -44,7 +44,7 @@ from repro.core.tree_utils import tree_norm
 from repro.models.model import ModelConfig, apply_train, init_params
 from repro.sharding import constraints as cons
 from repro.sharding.rules import batch_specs, param_specs, state_sharding
-from .mesh import num_workers, set_mesh, worker_axes
+from .mesh import num_workers, worker_axes
 
 __all__ = [
     "ByzTrainConfig",
@@ -78,6 +78,10 @@ class ByzTrainConfig:
     # per-worker gradients then shard over data x model and fit HBM
     # (see DESIGN.md "the per-worker-gradient memory wall").
     worker_axes_override: tuple = ()
+    # Number of workers.  0 => one per device of the worker axes.  More
+    # workers than devices (a multiple of them) share devices through the
+    # vmap over the worker axis; that needs the naive placement.
+    n_workers: int = 0
     seed: int = 0
 
     @classmethod
@@ -193,15 +197,21 @@ def make_train_step(model_cfg: ModelConfig, mesh, cfg: ByzTrainConfig):
     fraction, so the trainer contains no aggregation wiring of its own.
     """
     plan = resolve_plan(cfg)
-    server = plan.build(mesh)
     attack_stage = _attack_stage(cfg)
     # cohort and worker axes are trainer-owned knobs when the plan leaves
     # them unset; an explicit plan.cohort / plan.schedule.worker_axes wins
     waxes = (tuple(plan.schedule.worker_axes)
              or tuple(cfg.worker_axes_override) or worker_axes(mesh))
-    W = 1
+    slots = 1
     for a in waxes:
-        W *= mesh.shape[a]
+        slots *= mesh.shape[a]
+    W = cfg.n_workers or slots
+    if W % slots:
+        raise PlanError(
+            f"n_workers={W} must be a multiple of the {slots} devices on "
+            f"the worker axes {waxes}"
+        )
+    server = plan.build(mesh, n_workers=W)
     C = plan.cohort or cfg.C or W
     spmd = waxes if len(waxes) > 1 else (waxes[0] if waxes else None)
 
@@ -398,50 +408,50 @@ def main():
 
     from repro.configs.registry import get_config, get_smoke_config
     from repro.data.pipeline import make_batch_iterator
+    from .cache import enable_compile_cache
     from .cli import (add_attack_args, add_plan_args, plan_from_args,
                       scenario_from_args)
-    from .mesh import make_debug_mesh, make_production_mesh
+    from .mesh import make_local_mesh
 
     ap = argparse.ArgumentParser(description="Byz-VR-MARINA-PP mesh trainer")
     ap.add_argument("--arch", default="minitron_8b")
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config + debug mesh (CPU-runnable)")
+                    help="reduced f32 config (CPU-runnable)")
+    ap.add_argument("--workers", type=int, default=0,
+                    help="number of workers (0: one per device of the worker "
+                         "axis; more than that needs --agg-schedule naive)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--per-worker-batch", type=int, default=2)
     ap.add_argument("--gamma", type=float, default=0.1)
     ap.add_argument("--n-byz", type=int, default=1)
     ap.add_argument("--shard-mode", default="tp")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--ckpt-dir", default="")
     add_plan_args(ap)  # --aggregator/--agg-schedule/--schedule/... (shared)
     add_attack_args(ap, attack="bf")  # --attack/--byz-frac/--z-max (shared)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         model_cfg = get_smoke_config(args.arch).replace(dtype="float32", remat=False)
-        mesh = make_debug_mesh(
-            data=max(len(jax.devices()) // 2, 1),
-            model=2 if len(jax.devices()) >= 2 else 1,
-        )
     else:
         model_cfg = get_config(args.arch)
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    mesh = make_local_mesh()
 
-    W = num_workers(mesh)
+    W = args.workers or num_workers(mesh)
     scenario = scenario_from_args(args)
     n_byz = scenario.n_byz(W) if scenario.byz_frac is not None else args.n_byz
     plan = plan_from_args(args, byz_bound=n_byz, clip_alpha=2.0)
     tc = ByzTrainConfig.from_plan(
         plan, gamma=args.gamma, n_byz=n_byz, attack=scenario.build(),
-        shard_mode=args.shard_mode,
+        shard_mode=args.shard_mode, n_workers=W,
     )
     print(f"[train] {model_cfg.name} on mesh {dict(mesh.shape)} "
           f"({W} workers, {tc.n_byz} byzantine, "
           f"agg={plan.aggregate.rule})")
     step_fn = make_train_step(model_cfg, mesh, tc)
     it = make_batch_iterator(model_cfg, W * args.per_worker_batch, args.seq)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(jax.random.PRNGKey(0), model_cfg)
         batch0 = next(it)
         g0 = jax.grad(lambda p: apply_train(p, model_cfg, batch0)[0])(params)

@@ -114,7 +114,11 @@ def _ssd_chunked(cfg, xh, dt, B_mat, C_mat, A, init_state=None):
         # decay matrices
         seg = cumq[:, :, None, :] - cumq[:, None, :, :]  # (B,Q,Q,H) log decay i<-j
         causal = jnp.tril(jnp.ones((Q, Q), bool))
-        L = jnp.where(causal[None, :, :, None], jnp.exp(seg), 0.0)  # (B,Q,Q,H)
+        # mask BEFORE the exp: above the diagonal seg is a growing positive
+        # sum that overflows exp to inf on strong decays, and the masked
+        # where's gradient would then be 0 * inf = NaN
+        seg = jnp.where(causal[None, :, :, None], seg, -jnp.inf)
+        L = jnp.exp(seg)  # (B,Q,Q,H)
         # intra-chunk (quadratic) term: y_i += sum_j L_ij (C_i.B_j) dt_j x_j
         CB = jnp.einsum("bqn,bpn->bqp", cq, bq, preferred_element_type=F32)  # (B,Q,Q)
         W = CB[:, :, :, None] * L  # (B,Q,Q,H)

@@ -72,12 +72,7 @@ class suspend_data_axis:
 
 
 def _mesh():
-    # jax < 0.5 has no ambient abstract mesh; constraints degrade to no-ops
-    # (the same behaviour as running un-meshed).
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is None:
-        return None
-    m = get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or m.empty or not m.axis_names:
         return None
     return m

@@ -19,3 +19,21 @@ def iter_eqns_outside_kernels(jaxpr):
                 yield from iter_eqns_outside_kernels(inner)
             elif isinstance(v, (list, tuple)):
                 stack.extend(v)
+
+
+def pallas_calls(jaxpr, name):
+    """Every pallas_call eqn reachable from ``jaxpr`` whose kernel was
+    launched with ``name=name``."""
+    return [
+        eqn for eqn in iter_eqns_outside_kernels(jaxpr)
+        if eqn.primitive.name == "pallas_call" and eqn.params["name"] == name
+    ]
+
+
+def block_shapes(eqn):
+    """The (in..., out...) block shapes of a pallas_call eqn as int
+    tuples."""
+    return [
+        tuple(getattr(b, "block_size", b) for b in bm.block_shape)
+        for bm in eqn.params["grid_mapping"].block_mappings
+    ]

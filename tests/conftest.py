@@ -1,24 +1,14 @@
 import os
-import sys
+
+import hypothesis
 
 # Tests run on the single real CPU device.  The multi-device dry-run tests
 # spawn subprocesses with XLA_FLAGS set there (device count locks at first
 # jax init, so it must NOT be set globally here).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # container has no hypothesis; use the deterministic shim
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_fallback
-
-    _hypothesis_fallback.install()
-    import hypothesis  # noqa: F401  (now the shim)
-
 # Under CI the property tests must be fully deterministic: a flaky random
-# example would make the new workflow's tier-1 job untrustworthy.  The
-# fallback shim is derandomized by construction (fixed-seed PRNG, no
-# database); real hypothesis gets an explicit derandomized profile.
+# example would make the tier-1 job untrustworthy.
 if os.environ.get("CI", "").lower() in ("1", "true"):
     hypothesis.settings.register_profile(
         "repro-ci", derandomize=True, deadline=None, database=None,

@@ -236,3 +236,26 @@ def test_bucketed_cm_resists_outlier_minority():
     xs = jnp.asarray(np.concatenate([good, byz]))
     out = bucketed_coordinate_median(xs, jax.random.PRNGKey(0), s=2)
     assert float(jnp.abs(out).max()) < 10.0
+
+
+@pytest.mark.parametrize("platform,interpret,auto",
+                         [("cpu", True, "jnp"), ("tpu", False, "pallas"),
+                          ("gpu", None, None)])
+def test_kernel_platform_fallbacks_only_on_cpu(monkeypatch, platform,
+                                               interpret, auto):
+    """Interpret mode and the jnp ``auto`` choice belong to the CPU alone:
+    the TPU compiles the kernels, and any other platform is refused
+    rather than silently interpreted."""
+    from repro.core.aggregators import resolve_backend
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    monkeypatch.setattr(ops, "_INTERPRET", None)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops._interpret()
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            resolve_backend("auto")
+    else:
+        assert ops._interpret() is interpret
+        assert resolve_backend("auto") == auto

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from _jaxpr_utils import block_shapes, pallas_calls
 from _jaxpr_utils import iter_eqns_outside_kernels as _eqns_outside_kernels
 
 from repro.kernels import (
@@ -275,10 +276,15 @@ def test_onehot_apply_bitwise_equals_weighted_row_sum():
 )
 def test_onehot_apply_only_streams_winner_row(multi, bucket_s, expect_onehot):
     """Plain unbucketed Krum's fused apply pass must be the
-    scalar-prefetch select_row kernel with a (1, TILE_D) x-block — the
-    DMA streams d bytes, not n*d; multi-Krum and bucketed selections
-    (genuine multi-row combinations) must keep the full row-sum pass."""
-    n, d = 8, 1100
+    scalar-prefetch select_row kernel whose x-block is the one sublane
+    tile group holding the winner (8 f32 rows of n = 32) — the DMA
+    streams row_tile * d elements, not n*d; multi-Krum and bucketed
+    selections (genuine multi-row combinations) must keep the full
+    row-sum pass."""
+    from repro.kernels.coordinate_median import TILE_D
+    from repro.kernels.krum import row_tile
+
+    n, d = 32, 1100
     rng = np.random.RandomState(0)
     xs = jnp.asarray(rng.randn(n, d).astype(np.float32))
     idx = jnp.asarray(rng.permutation(n).astype(np.int32))
@@ -289,33 +295,28 @@ def test_onehot_apply_only_streams_winner_row(multi, bucket_s, expect_onehot):
     )(xs, idx)
     text = str(jaxpr)
     if expect_onehot:
-        assert "_select_row_kernel" in text
-        assert "_row_combine_kernel" not in text
-        # structural traffic assertion: the apply kernel's x operand is
-        # mapped in (1, TILE_D) blocks — one row, not the (n, TILE_D)
-        # full-matrix block of the row-sum pass
-        for eqn in jaxpr.jaxpr.eqns:
-            if eqn.primitive.name != "pallas_call":
-                continue
-            if "_select_row_kernel" not in str(
-                eqn.params.get("name_and_src_info", "")
-            ):
-                continue
-            gm = eqn.params.get("grid_mapping")
-            shapes = [
-                tuple(bm.block_shape)
-                for bm in getattr(gm, "block_mappings", ())
-            ]
-            if shapes:  # introspectable on the pinned jax lines
-                assert all(s[0] == 1 for s in shapes), shapes
+        assert "name=krum_select_row" in text
+        assert "name=weighted_row_sum" not in text
+        # structural traffic assertion: the apply kernel maps its x operand
+        # in (row_tile, TILE_D) blocks — one sublane tile group, not the
+        # (n, TILE_D) full-matrix block of the row-sum pass — and writes
+        # one (1, TILE_D) output row per step
+        calls = pallas_calls(jaxpr.jaxpr, "krum_select_row")
+        assert len(calls) == 1, calls
+        shapes = block_shapes(calls[0])
+        rb = row_tile(n, xs.dtype)
+        assert rb == 8 < n
+        assert shapes == [(rb, TILE_D), (1, TILE_D)], shapes
     else:
-        assert "_row_combine_kernel" in text
-        assert "_select_row_kernel" not in text
+        assert "name=weighted_row_sum" in text
+        assert "name=krum_select_row" not in text
 
 
 def test_onehot_apply_traffic_model():
-    """The modeled apply-pass traffic must show the d-vs-n*d cut the
-    fast path exists for (the bench gate pins fused_bytes)."""
+    """The modeled apply-pass traffic must count what select_row streams
+    (the bench gate pins fused_bytes): the winner's sublane tile group of
+    row_tile rows instead of all n — 8 f32 rows of n = 16 — and no cut
+    at all where the tile group is the whole matrix (bf16, n = 4)."""
     import os
     import sys
 
@@ -326,9 +327,11 @@ def test_onehot_apply_traffic_model():
 
     n, d = 16, 1 << 16
     tm = traffic_model_krum_apply(n, d)
-    assert tm["fused_bytes"] == 2 * d * 4  # winner row in + (d,) out
+    assert tm["fused_bytes"] == (8 + 1) * d * 4  # winner tile in + (d,) out
     assert tm["full_bytes"] == (n + 1) * d * 4
-    assert tm["traffic_reduction"] == pytest.approx((n + 1) / 2)
+    assert tm["traffic_reduction"] == pytest.approx((n + 1) / 9)
+    tm = traffic_model_krum_apply(4, d, itemsize=2)
+    assert tm["fused_bytes"] == tm["full_bytes"] == (4 + 1) * d * 2
 
 
 # ---------------------------------------------------------------------------

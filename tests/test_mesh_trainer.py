@@ -170,7 +170,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.api import AggregatorSpec, BucketSpec, ScheduleSpec, ServerPlan
-from repro.launch.mesh import make_debug_mesh, set_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.train import ByzTrainConfig, robust_aggregate
 
 def mk_cfg(agg, sched, backend, inner="sequential", sle=0):
@@ -190,7 +190,7 @@ tree = {
 }
 mask = jnp.asarray([True, True, False, True])
 key = jax.random.PRNGKey(0)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     tree = jax.device_put(tree, NamedSharding(mesh, P("data")))
     for agg in ("cm", "tm", "mean", "cclip", "rfa", "krum", "multi_krum",
                 "bucket_cm", "bucket_krum"):
@@ -217,6 +217,66 @@ print("EQUIV_OK")
     assert "EQUIV_OK" in r.stdout
 
 
+def test_naive_placement_keeps_grad_sharding():
+    """On a (data, model) mesh the naive placement gathers the worker dim
+    only: each chip aggregates all W rows of its own model-axis shard
+    (per-chip block (W, 6, 16) of a (W, 6, 32) leaf), the result keeps
+    the grad sharding, and the row statistics of the non-coordinate-wise
+    rules are psum'd over the model axis — equal to the replicated
+    naive result and to the sharded placement."""
+    script = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.api import AggregatorSpec, ScheduleSpec, ServerPlan
+from repro.launch.mesh import make_debug_mesh
+from repro.launch.train import ByzTrainConfig, robust_aggregate
+
+mesh = make_debug_mesh(2, 2)
+rng = np.random.RandomState(0)
+tree = {"a": jnp.asarray(rng.randn(2, 6, 32).astype(np.float32)),
+        "b": jnp.asarray(rng.randn(2, 17).astype(np.float32))}
+specs = {"a": P(None, "model"), "b": P()}
+mask = jnp.ones((2,), bool)
+key = jax.random.PRNGKey(0)
+
+def cfg(agg, sched, sle):
+    return ByzTrainConfig.from_plan(ServerPlan(
+        aggregate=AggregatorSpec(agg),
+        schedule=ScheduleSpec(placement=sched, backend="pallas",
+                              superleaf_elems=sle)))
+
+def agg_fn(c, bs):
+    return jax.jit(lambda t, m, k: robust_aggregate(
+        t, m, k, mesh=mesh, cfg=c, radius=jnp.float32(3.0), base_specs=bs))
+
+with jax.set_mesh(mesh):
+    t = {k: jax.device_put(v, NamedSharding(mesh, P("data", *specs[k])))
+         for k, v in tree.items()}
+    for agg in ("cm", "krum", "cclip"):
+        for sle in (0, 64):
+            got = agg_fn(cfg(agg, "naive", sle), specs)(t, mask, key)
+            assert got["a"].sharding.spec == P(None, "model"), got["a"].sharding
+            for other in (agg_fn(cfg(agg, "naive", sle), None)(t, mask, key),
+                          agg_fn(cfg(agg, "sharded", sle), specs)(t, mask, key)):
+                for k in tree:
+                    np.testing.assert_allclose(
+                        np.asarray(other[k]), np.asarray(got[k]), atol=3e-5,
+                        err_msg=f"{agg} superleaf={sle} {k}")
+    jp = jax.make_jaxpr(lambda t, m, k: robust_aggregate(
+        t, m, k, mesh=mesh, cfg=cfg("cm", "naive", 0), base_specs=specs)
+    )(t, mask, key)
+    (sm,) = [e for e in jp.jaxpr.eqns if e.primitive.name == "shard_map"]
+    shapes = [v.aval.shape for v in sm.params["jaxpr"].invars]
+    assert shapes[:2] == [(2, 6, 16), (2, 17)], shapes
+print("NAIVE_SHARD_OK")
+"""
+    r = _run([sys.executable, "-c", script])
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "NAIVE_SHARD_OK" in r.stdout
+
+
 @pytest.mark.slow
 def test_whole_tree_mesh_krum_matches_engine_whole_message_bitwise():
     """Algorithm 1 applies the robust aggregator to the WHOLE message.
@@ -236,7 +296,7 @@ from repro.core.aggregators import make_aggregator
 from repro.core.clipping import clip_factor
 from repro.core.tree_utils import tree_norm
 from repro.api import AggregatorSpec, ScheduleSpec, ServerPlan
-from repro.launch.mesh import make_debug_mesh, set_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.train import ByzTrainConfig, robust_aggregate
 
 def mk_cfg(agg, backend):
@@ -300,7 +360,7 @@ for backend in ("jnp", "pallas"):
                                         base)
             g2 = g1
             tr1, tr2 = [], []
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 for t in range(8):
                     k = jax.random.fold_in(key, t)
                     m1, m2 = messages(g1, k), messages(g2, k)
@@ -319,7 +379,7 @@ for backend in ("jnp", "pallas"):
 
 # the sharded whole-tree path must never build the stacked message
 cfg = mk_cfg("krum", "pallas")
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     jaxpr = jax.make_jaxpr(
         lambda t, m, k: robust_aggregate(t, m, k, mesh=mesh, cfg=cfg,
                                          radius=jnp.float32(2.5))
@@ -350,7 +410,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.api import AggregatorSpec, BucketSpec, ScheduleSpec, ServerPlan
-from repro.launch.mesh import make_debug_mesh, set_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.train import ByzTrainConfig, robust_aggregate
 
 def mk_cfg(agg, sched, sle):
@@ -371,7 +431,7 @@ tree = {
 mask = jnp.asarray([True, True, False, True])
 key = jax.random.PRNGKey(0)
 radius = jnp.float32(3.0)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     tree = jax.device_put(tree, NamedSharding(mesh, P("data")))
     for agg in ("cm", "tm", "mean", "cclip", "rfa", "krum", "multi_krum",
                 "bucket_cm", "bucket_krum", "bucket_rfa"):
@@ -407,7 +467,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import make_debug_mesh, set_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.train import ByzTrainConfig, robust_aggregate
 
 mesh = make_debug_mesh(4, 2)
@@ -447,7 +507,7 @@ for agg in ("krum", "centered_clip"):
             t, m, k, mesh=mesh, cfg=cfg, radius=jnp.float32(2.5)))
         g = jax.tree_util.tree_map(lambda l: jnp.zeros(l.shape[1:]), base)
         tr = []
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for t in range(6):
                 k = jax.random.fold_in(key, t)
                 a = jagg(messages(g, k), mask, k)
@@ -478,7 +538,7 @@ def test_whole_tree_selection_in_process_naive_matches_engine():
     from repro.core.aggregators import make_aggregator
     from repro.core.clipping import clip_factor
     from repro.core.tree_utils import tree_norm
-    from repro.launch.mesh import make_debug_mesh, set_mesh
+    from repro.launch.mesh import make_debug_mesh
     from repro.launch.train import robust_aggregate
 
     mesh = make_debug_mesh(1, 1)
@@ -493,7 +553,7 @@ def test_whole_tree_selection_in_process_naive_matches_engine():
     factors = clip_factor(jax.vmap(tree_norm)(tree), radius).astype(
         jnp.float32
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for backend in ("jnp", "pallas"):
             for name in ("krum", "multi_krum", "bucket_krum"):
                 cfg = _mk_cfg(name, placement="naive", backend=backend,
@@ -524,7 +584,7 @@ def test_sharded_fused_path_jaxpr_no_standalone_clipped_matrix():
     INSIDE the fused clip_then_aggregate kernel: the jaxpr contains the
     fused kernel launch and no elementwise multiply materializing the
     clipped (W, chunk) message block outside a kernel."""
-    from repro.launch.mesh import make_debug_mesh, set_mesh
+    from repro.launch.mesh import make_debug_mesh
     from repro.launch.train import robust_aggregate
 
     mesh = make_debug_mesh(1, 1)  # single-device mesh: tracing only
@@ -532,7 +592,7 @@ def test_sharded_fused_path_jaxpr_no_standalone_clipped_matrix():
     tree = {"a": jnp.asarray(rng.randn(1, 8, 64).astype(np.float32))}
     mask = jnp.ones((1,), bool)
     key = jax.random.PRNGKey(0)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cfg = _mk_cfg("cm", placement="sharded", backend="pallas")
         jaxpr = jax.make_jaxpr(
             lambda t, m, k: robust_aggregate(
@@ -542,7 +602,7 @@ def test_sharded_fused_path_jaxpr_no_standalone_clipped_matrix():
     text = str(jaxpr)
     # the fused kernel is launched ...
     assert "pallas_call" in text
-    assert "_clip_agg_kernel" in text or "clip_aggregate" in text
+    assert "name=clip_aggregate" in text
     # ... and no multiply outside a kernel produces the (W, chunk) clipped
     # message block (W = 1 worker, chunk = the full 8*64 flat block here)
     w, chunk = 1, 8 * 64
@@ -556,6 +616,95 @@ def test_sharded_fused_path_jaxpr_no_standalone_clipped_matrix():
         )
     ]
     assert not bad, f"clipped matrix materialized outside kernel: {bad}"
+
+
+def test_several_workers_share_one_device():
+    """W = 4 workers on a one-device mesh (the one-chip trainer): the
+    naive placement vmaps the per-worker gradients over the W rows.
+    With only full-gradient rounds and no clip, g^{k+1} is the
+    coordinate median of the four per-worker gradients, each the
+    gradient of the loss on its quarter of the batch at x^{k+1}; the
+    trainer's robust composition (cm + alpha = 2 clip, one bit-flip
+    worker) runs a few steps and stays finite."""
+    from repro.api import ClipSpec
+    from repro.configs.registry import get_smoke_config
+    from repro.data.pipeline import make_batch_iterator
+    from repro.launch.mesh import make_debug_mesh
+    from repro.launch.train import MeshTrainState, make_train_step
+    from repro.models import apply_train, init_params
+
+    cfg = get_smoke_config("mamba2_780m").replace(dtype="float32",
+                                                  remat=False)
+    mesh = make_debug_mesh(1, 1)
+    W = 4
+    it = make_batch_iterator(cfg, W * 2, 64, seed=0)
+    batch = next(it)
+
+    def loss(p, b):
+        return apply_train(p, cfg, b)[0]
+
+    cm_plan = ServerPlan(
+        aggregate=AggregatorSpec("cm"),
+        schedule=ScheduleSpec(placement="naive", backend="pallas"),
+    )
+    robust_plan = ServerPlan(
+        aggregate=AggregatorSpec("cm", byz_bound=1),
+        clip=ClipSpec(alpha=2.0),
+        schedule=ScheduleSpec(placement="naive", backend="pallas"),
+    )
+    with jax.set_mesh(mesh):
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        g0 = jax.grad(loss)(params, batch)
+        state0 = MeshTrainState(params=params, g=g0,
+                                key=jax.random.PRNGKey(1), step=jnp.int32(0))
+
+        tc = ByzTrainConfig.from_plan(cm_plan, gamma=0.1, p=1.0, n_byz=0,
+                                      attack="none", n_workers=W)
+        out = jax.jit(make_train_step(cfg, mesh, tc))(state0, batch)
+        quarters = [
+            jax.tree_util.tree_map(lambda l: l[2 * i:2 * i + 2], batch)
+            for i in range(W)
+        ]
+        per_worker = [jax.grad(loss)(out.params, q) for q in quarters]
+        want = jax.tree_util.tree_map(
+            lambda *g: np.median(np.stack(g), axis=0), *per_worker
+        )
+        for a, b in zip(jax.tree_util.tree_leaves(out.g),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), b,
+                                       rtol=1e-4, atol=1e-6)
+
+        tc = ByzTrainConfig.from_plan(robust_plan, gamma=0.1, p=0.5,
+                                      n_byz=1, attack="bf", n_workers=W)
+        step = jax.jit(make_train_step(cfg, mesh, tc))
+        state = state0
+        for _ in range(3):
+            state = step(state, next(it))
+        assert all(bool(jnp.isfinite(l).all())
+                   for l in jax.tree_util.tree_leaves(state))
+        assert np.isfinite(float(loss(state.params, batch)))
+
+
+def test_worker_count_plan_errors():
+    """More workers than devices needs the naive placement, and the
+    workers must fill the devices evenly; both refusals are PlanErrors
+    raised when the step is built, before anything is traced."""
+    from repro.api import PlanError
+    from repro.configs.registry import get_smoke_config
+    from repro.launch.mesh import make_debug_mesh
+    from repro.launch.train import make_train_step
+
+    cfg = get_smoke_config("mamba2_780m")
+    # the default plan is the sharded placement: one worker per device
+    with pytest.raises(PlanError, match="one worker per device"):
+        make_train_step(cfg, make_debug_mesh(1, 1),
+                        ByzTrainConfig(n_workers=4))
+    naive = ServerPlan(aggregate=AggregatorSpec("cm"),
+                       schedule=ScheduleSpec(placement="naive"))
+    two_slots = jax.sharding.AbstractMesh((2, 1), ("data", "model"))
+    with pytest.raises(PlanError, match="must be a multiple of the 2"):
+        make_train_step(cfg, two_slots,
+                        ByzTrainConfig.from_plan(naive, n_workers=3))
 
 
 def test_train_cfg_validation():
@@ -595,7 +744,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.api import AggregatorSpec, ScheduleSpec, ServerPlan
-from repro.launch.mesh import make_debug_mesh, set_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.train import ByzTrainConfig, MeshTrainState, make_train_step
 from repro.models import ModelConfig, apply_train, init_params
 from repro.data.pipeline import make_batch_iterator
@@ -615,7 +764,7 @@ for agg in ("cm", "mean"):
                                       attack="gauss", p=0.125)
     step = make_train_step(cfg, mesh, tc)
     it = make_batch_iterator(cfg, 8, 64, seed=3)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(jax.random.PRNGKey(0), cfg)
         batch0 = next(it)
         g0 = jax.grad(lambda p: apply_train(p, cfg, batch0)[0])(params)

@@ -87,6 +87,36 @@ def test_ssd_carried_state_across_calls():
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h_full), rtol=2e-4, atol=2e-4)
 
 
+def test_ssd_gradients_finite_under_strong_decay():
+    """A chunk whose summed decay exceeds float32's exp range (sum dt*|A|
+    > 88 over the chunk, as large dt does at the published chunk of 256)
+    must still have finite gradients: the above-diagonal segment sums are
+    masked before the exp, so no inf meets the mask's zero gradient."""
+    cfg = ModelConfig(
+        name="t", n_layers=1, d_model=32, n_heads=1, n_kv_heads=1, d_ff=0,
+        vocab=16, mixer_pattern=("ssm",), mlp_pattern=("none",),
+        ssm_state=8, ssm_head_dim=4, ssm_chunk=16, dtype="float32",
+    )
+    rng = np.random.RandomState(2)
+    Bsz, S, H, P, N = 1, 16, 2, 4, 8
+    xh = jnp.asarray(rng.randn(Bsz, S, H, P).astype(np.float32))
+    dt = jnp.full((Bsz, S, H), 8.0, jnp.float32)  # 16 * 8 * 16 >> 88
+    Bm = jnp.asarray(rng.randn(Bsz, S, N).astype(np.float32))
+    Cm = jnp.asarray(rng.randn(Bsz, S, N).astype(np.float32))
+    A = -jnp.asarray([1.0, 16.0], jnp.float32)
+
+    def loss(xh, dt, Bm, Cm):
+        y, h = ssm_mod._ssd_chunked(cfg, xh, dt, Bm, Cm, A)
+        return jnp.sum(y) + jnp.sum(h)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3))(xh, dt, Bm, Cm)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+    y, h = ssm_mod._ssd_chunked(cfg, xh, dt, Bm, Cm, A)
+    y_ref, h_ref = _ssd_sequential(xh, dt, Bm, Cm, A)
+    np.testing.assert_allclose(np.asarray(y), y_ref, rtol=2e-4, atol=2e-4)
+
+
 # ---------------------------------------------------------------------------
 # MoE dispatch vs loop-over-experts
 # ---------------------------------------------------------------------------

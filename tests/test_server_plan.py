@@ -83,14 +83,14 @@ def test_superleaf_on_iterative_rule_warns_block_partition():
 
 
 def test_worker_rows_vs_mesh_w_raises():
-    from repro.launch.mesh import make_debug_mesh, set_mesh
+    from repro.launch.mesh import make_debug_mesh
 
     mesh = make_debug_mesh(1, 1)
     plan = ServerPlan(
         aggregate=AggregatorSpec("cm"),
         schedule=ScheduleSpec(placement="sharded"),
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = plan.build(mesh)
         with pytest.raises(PlanError, match="one row per worker"):
             step({"a": jnp.ones((2, 4))}, mask=jnp.ones(2, bool), key=KEY)
@@ -285,7 +285,7 @@ def test_robust_aggregate_vs_plan_registry_trajectory_bitwise(backend):
     trajectories (the naive placement runs in-process; the
     sharded/pipelined placements are covered by the 8-device subprocess
     tests, which route through the same plan)."""
-    from repro.launch.mesh import make_debug_mesh, set_mesh
+    from repro.launch.mesh import make_debug_mesh
     from repro.launch.train import ByzTrainConfig, resolve_plan, robust_aggregate
 
     mesh = make_debug_mesh(1, 1)
@@ -297,7 +297,7 @@ def test_robust_aggregate_vs_plan_registry_trajectory_bitwise(backend):
     mask = jnp.asarray([1, 1, 0, 1, 1, 1], bool)
     radius = jnp.float32(2.0)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name, bucket_s in (("cm", 0), ("tm", 0), ("mean", 0),
                                ("cclip", 0), ("rfa", 0), ("krum", 0),
                                ("multi_krum", 0), ("cm", 2), ("krum", 2),

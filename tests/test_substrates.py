@@ -199,3 +199,57 @@ def test_analytic_flops_sane():
     assert 0.3 * approx < fl["total"] < 3 * approx
     dec = step_flops(cfg, seq=32768, batch=128, mode="decode")
     assert dec["total"] < fl["total"] / 1e3
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache of the command-line entry points
+# ---------------------------------------------------------------------------
+
+_CACHE_SCRIPT = """
+import jax, jax.numpy as jnp
+from repro.launch.cache import enable_compile_cache
+hits = []
+jax.monitoring.register_event_listener(
+    lambda event, **kw: hits.append(event)
+    if event == "/jax/compilation_cache/cache_hits" else None)
+print("DIR", enable_compile_cache())
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.jit(lambda x: jnp.sin(x) * 3 + 1)(jnp.ones(8)).block_until_ready()
+print("HITS", len(hits))
+"""
+
+
+def _cache_run(env):
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c", _CACHE_SCRIPT], env=env, cwd=root,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    lines = dict(l.split(" ", 1) for l in r.stdout.splitlines()
+                 if l.startswith(("DIR ", "HITS ")))
+    return lines["DIR"], int(lines["HITS"])
+
+
+def test_compile_cache_uses_env_dir_and_hits_on_second_run():
+    """JAX_COMPILATION_CACHE_DIR, when set, is where entries land;
+    unset, the cache is the fixed .jax_cache/ at the checkout root.
+    Either way a second process finds the first one's entries."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    base = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    base["PYTHONPATH"] = os.path.join(root, "src")
+    with tempfile.TemporaryDirectory() as d:
+        env = dict(base, JAX_COMPILATION_CACHE_DIR=d)
+        where, hits = _cache_run(env)
+        assert where == d and hits == 0
+        assert os.listdir(d), "no cache entry written"
+        _, hits = _cache_run(env)
+        assert hits >= 1
+    where, _ = _cache_run(base)
+    assert where == os.path.join(root, ".jax_cache")
+    _, hits = _cache_run(base)
+    assert hits >= 1

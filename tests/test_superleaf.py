@@ -28,7 +28,7 @@ from repro.api import (
     ServerPlan,
 )
 from repro.core.tree_utils import tree_superleaf_pack
-from repro.launch.mesh import make_debug_mesh, set_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.train import ByzTrainConfig, robust_aggregate
 
 
@@ -148,7 +148,7 @@ def test_packed_naive_aggregate_bitwise_equals_per_leaf(backend):
     mask = jnp.asarray([1, 1, 0, 1, 1, 1], bool)
     key = jax.random.PRNGKey(3)
     mesh = make_debug_mesh(1, 1)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name, bucket_s in _EXACT_RULES:
             for radius in (jnp.float32(2.0), None):
                 outs = {}
@@ -193,7 +193,7 @@ def test_pipelined_schedule_bitwise_equals_sequential_inprocess(backend):
     mask = jnp.ones((1,), bool)
     key = jax.random.PRNGKey(3)
     mesh = make_debug_mesh(1, 1)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name, bucket_s in _ALL_RULES:
             for chunk in (0, 16):
                 outs = {}
