@@ -200,7 +200,7 @@ def _naive_aggregate(tree_w, mask, key, factors, *, agg, leaf_agg,
 
 
 def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
-                       base_specs=None, radius=None):
+                       base_specs=None, radius=None, with_factors=False):
     """Aggregate a worker-stacked pytree (leaves (W, ...)) into the
     aggregated pytree (leaves (...)) under ``spec`` on ``mesh``.
 
@@ -208,7 +208,9 @@ def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
     set, l2-clips every worker message at that radius by its *global*
     tree norm before aggregation (the Algorithm-1 server re-clip as a
     2-stream fused step — batched norm pass, then per-chip
-    ``clip_then_aggregate`` with precomputed factors).
+    ``clip_then_aggregate`` with precomputed factors).  ``with_factors``
+    also returns those (n,) factors (ones without a radius), so a caller
+    can count clipped rows without a second pass over the messages.
 
     ``base_specs``: PartitionSpec pytree of the UNSTACKED leaves (the grad
     sharding).  The sharded placement runs a fully-manual shard_map
@@ -216,20 +218,36 @@ def run_mesh_aggregate(tree_w, mask, key, *, mesh, agg, spec: ScheduleSpec,
     chip-local — flattening a model-sharded dim under auto propagation
     silently all-gathers it.  The all_to_all lands a chip-local (W, d/W)
     block on every chip — exactly the fused kernel's input shape.
+
+    The norm pass runs under the ``clip_norm`` name scope and the rest
+    under ``aggregate``: metadata that a profile reads, nothing that runs.
     """
+    n_rows = jax.tree_util.tree_leaves(tree_w)[0].shape[0]
+    use_factors = radius is not None
+    if use_factors:
+        with jax.named_scope("clip_norm"):
+            factors = clip_factor(_worker_message_norms(tree_w),
+                                  radius).astype(F32)
+    else:
+        factors = jnp.ones((n_rows,), F32)
+    with jax.named_scope("aggregate"):
+        out = _aggregate_placed(tree_w, mask, key, factors, mesh=mesh,
+                                agg=agg, spec=spec, base_specs=base_specs,
+                                use_factors=use_factors)
+    return (out, factors) if with_factors else out
+
+
+def _aggregate_placed(tree_w, mask, key, factors, *, mesh, agg,
+                      spec: ScheduleSpec, base_specs, use_factors):
+    """``run_mesh_aggregate``'s aggregation on the spec's placement, with
+    the clip factors already computed."""
     leaf_agg = leaf_agg_of(agg)
     two_phase = agg.supports_two_phase
     pipelined = spec.blocks == "pipelined"
     chunk_elems = int(spec.superleaf_elems)
     waxes = mesh_worker_axes(mesh, spec.worker_axes)
     W = mesh_worker_count(mesh, spec.worker_axes)
-
     n_rows = jax.tree_util.tree_leaves(tree_w)[0].shape[0]
-    use_factors = radius is not None
-    if use_factors:
-        factors = clip_factor(_worker_message_norms(tree_w), radius).astype(F32)
-    else:
-        factors = jnp.ones((n_rows,), F32)
 
     if base_specs is None:
         base_specs = jax.tree_util.tree_map(

@@ -560,6 +560,7 @@ class ServerStep:
         stage.
       - mesh builds additionally take ``base_specs`` (the unstacked grad
         PartitionSpecs) and run the configured collective schedule;
+        ``with_factors=True`` returns ``(aggregate, clip factors)``;
         engine builds (mesh=None) run whole-message semantics through the
         fused dispatch-layer kernels.
 
@@ -610,7 +611,8 @@ class ServerStep:
     # -- the step ------------------------------------------------------------
 
     def __call__(self, msgs, mask=None, key=None, radius=None,
-                 base_specs=None, _allow_static_clip=True):
+                 base_specs=None, with_factors=False,
+                 _allow_static_clip=True):
         plan = self.plan
         if radius is None and _allow_static_clip and plan.clip is not None \
                 and plan.clip.radius is not None:
@@ -621,11 +623,12 @@ class ServerStep:
             return run_mesh_aggregate(
                 msgs, mask, key, mesh=self.mesh, agg=self.aggregator,
                 spec=plan.schedule, base_specs=base_specs, radius=radius,
+                with_factors=with_factors,
             )
-        if base_specs is not None:
+        if base_specs is not None or with_factors:
             raise PlanError(
-                "base_specs is a mesh-build argument; this ServerStep was "
-                "built with mesh=None"
+                "base_specs and with_factors are mesh-build arguments; this "
+                "ServerStep was built with mesh=None"
             )
         if radius is None:
             return self.aggregator(msgs, mask=mask, key=key)

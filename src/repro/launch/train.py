@@ -1,7 +1,8 @@
 """Distributed Byz-VR-MARINA-PP trainer for the production mesh.
 
-Mapping (see DESIGN.md §4): worker == (pod, data) mesh index; per-worker
-variance-reduced gradients are computed with ``jax.vmap(..,
+Mapping: worker == (pod, data) mesh index, so a worker's data shard and
+its gradient live on the same chips and no batch moves between workers;
+per-worker variance-reduced gradients are computed with ``jax.vmap(..,
 spmd_axis_name=worker_axes)`` (so XLA pins the worker dim to the data axes
 and never replicates it), then clipped/compressed messages are robustly
 aggregated ACROSS the worker axes by the trainer's ``ServerPlan`` — the
@@ -21,6 +22,13 @@ README migration table).
 
 ``robust_aggregate`` remains the long-standing functional entry point and
 now simply runs ``plan.build(mesh)`` on the config's resolved plan.
+
+The step names its parts with ``jax.named_scope`` (``round_full``,
+``round_diff``, ``worker_grads``, ``compress``, ``attack``, ``clip_norm``,
+``aggregate``, ``update``): metadata in the compiled program's ``op_name``,
+which a profile reads; nothing runs for them.  A state whose ``stats`` is
+a ``TrainStats`` (``init_train_stats()``) also counts the work each step
+did; ``stats=None`` compiles the step without the counters.
 """
 from __future__ import annotations
 
@@ -49,6 +57,8 @@ from .mesh import num_workers, worker_axes
 __all__ = [
     "ByzTrainConfig",
     "MeshTrainState",
+    "TrainStats",
+    "init_train_stats",
     "make_train_step",
     "robust_aggregate",
     "abstract_state",
@@ -71,12 +81,15 @@ class ByzTrainConfig:
     # (``resolve_plan``).
     plan: Optional[ServerPlan] = None
     attack: str = "bf"  # "none" | "bf" | "gauss"
-    shard_mode: str = "tp"  # "tp" | "fsdp_tp"
+    shard_mode: str = "tp"  # "tp" | "fsdp_tp" | "zero3"
     # Workers normally enumerate over every batch-like mesh axis
     # (pod x data).  For FSDP-scale models on the multi-pod mesh, set
     # ("pod",) so each pod is ONE worker and "data" stays free for FSDP —
-    # per-worker gradients then shard over data x model and fit HBM
-    # (see DESIGN.md "the per-worker-gradient memory wall").
+    # per-worker gradients then shard over data x model and fit HBM.  The
+    # step holds W per-worker gradient trees (2W on a difference round),
+    # each as large as the parameters: with one worker per data index and
+    # the model split over "model" alone, a large model's gradient shards
+    # outgrow a chip's HBM.
     worker_axes_override: tuple = ()
     # Number of workers.  0 => one per device of the worker axes.  More
     # workers than devices (a multiple of them) share devices through the
@@ -111,11 +124,25 @@ def resolve_plan(cfg: ByzTrainConfig) -> ServerPlan:
     )
 
 
+class TrainStats(NamedTuple):
+    """Cumulative int32 counts of the work the steps did."""
+    rounds_full: jnp.ndarray  # full-gradient rounds
+    rows_sampled: jnp.ndarray  # cohort rows, all rounds
+    rounds_byzantine_only: jnp.ndarray  # difference rounds, no honest row
+    rows_clipped: jnp.ndarray  # sampled rows whose clip factor is below 1
+    worker_evals: jnp.ndarray  # per-worker gradients evaluated
+
+
+def init_train_stats() -> TrainStats:
+    return TrainStats(*(jnp.zeros((), jnp.int32) for _ in TrainStats._fields))
+
+
 class MeshTrainState(NamedTuple):
     params: object  # x^k
     g: object  # g^k (gradient-shaped)
     key: jax.Array
     step: jnp.ndarray
+    stats: Optional[TrainStats] = None  # None: the step counts nothing
 
 
 # ---------------------------------------------------------------------------
@@ -230,16 +257,18 @@ def make_train_step(model_cfg: ModelConfig, mesh, cfg: ByzTrainConfig):
         return loss
 
     def per_worker_grads(params, wbatches):
+        """(the W per-worker gradients, how many were evaluated)."""
         gfn = lambda b: jax.grad(loss_fn)(params, b)
-        if spmd is None:
-            return jax.vmap(gfn)(wbatches)
-        ctx = (
-            cons.override_data_axes(("model",))
-            if cfg.shard_mode == "zero3"
-            else cons.override_data_axes(("pod", "data"))
-        )
-        with cons.suspend_data_axis(waxes), ctx:
-            return jax.vmap(gfn, spmd_axis_name=spmd)(wbatches)
+        with jax.named_scope("worker_grads"):
+            if spmd is None:
+                return jax.vmap(gfn)(wbatches), W
+            ctx = (
+                cons.override_data_axes(("model",))
+                if cfg.shard_mode == "zero3"
+                else cons.override_data_axes(("pod", "data"))
+            )
+            with cons.suspend_data_axis(waxes), ctx:
+                return jax.vmap(gfn, spmd_axis_name=spmd)(wbatches), W
 
     pspecs_cache = {}
 
@@ -291,18 +320,21 @@ def make_train_step(model_cfg: ModelConfig, mesh, cfg: ByzTrainConfig):
     def train_step(state: MeshTrainState, batch):
         key, k_bern, k_cohort, k_q, k_att, k_agg = jax.random.split(state.key, 6)
         c_k = jax.random.bernoulli(k_bern, cfg.p)
+        counting = state.stats is not None
 
         # x^{k+1} = x^k - gamma g^k ; lambda = alpha ||x+ - x|| = alpha*gamma*||g||
-        params_new = jax.tree_util.tree_map(
-            lambda x, g: (x - cfg.gamma * g.astype(F32)).astype(x.dtype),
-            state.params,
-            state.g,
-        )
+        with jax.named_scope("update"):
+            params_new = jax.tree_util.tree_map(
+                lambda x, g: (x - cfg.gamma * g.astype(F32)).astype(x.dtype),
+                state.params,
+                state.g,
+            )
         if server.clips and plan.clip.radius is not None:
             lam = jnp.float32(plan.clip.radius)
         else:
             alpha = plan.clip.alpha if server.clips else 0.0
-            lam = alpha * cfg.gamma * tree_norm(state.g)
+            with jax.named_scope("clip_norm"):
+                lam = alpha * cfg.gamma * tree_norm(state.g)
 
         # cohort mask over workers; byz mask static
         perm = jax.random.permutation(k_cohort, W)
@@ -316,55 +348,94 @@ def make_train_step(model_cfg: ModelConfig, mesh, cfg: ByzTrainConfig):
             lambda l: l.reshape((W, l.shape[0] // W) + l.shape[1:]), batch
         )
 
-        grads_new = grad_constraint(per_worker_grads(params_new, wbatch))
+        grads_new, evals_new = per_worker_grads(params_new, wbatch)
+        grads_new = grad_constraint(grads_new)
 
         def diff_branch(_):
-            grads_old = grad_constraint(per_worker_grads(state.params, wbatch))
-            diff = jax.tree_util.tree_map(
-                lambda a, b: a - b, grads_new, grads_old
-            )
+            with jax.named_scope("round_diff"):
+                grads_old, evals_old = per_worker_grads(state.params, wbatch)
+                grads_old = grad_constraint(grads_old)
 
-            def compress(i, d_i):
-                if compress_frac > 0.0:
-                    d_i = _leafwise_randk(
-                        jax.random.fold_in(k_q, i), d_i, compress_frac
+                def compress(i, d_i):
+                    if compress_frac > 0.0:
+                        d_i = _leafwise_randk(
+                            jax.random.fold_in(k_q, i), d_i, compress_frac
+                        )
+                    return d_i
+
+                with jax.named_scope("compress"):
+                    diff = jax.tree_util.tree_map(
+                        lambda a, b: a - b, grads_new, grads_old
                     )
-                return d_i
-
-            honest = jax.vmap(compress, in_axes=(0, 0))(jnp.arange(W), diff)
-            # the in-graph omniscient attack stage: byzantine rows see the
-            # sampled honest messages of THIS round (ALIE/IPM statistics
-            # computed per leaf == per coordinate of the full message)
-            msgs = attack_stage.corrupt_tree(
-                honest, good_mask=~byz, sampled=sampled, key=k_att
-            )
-            msgs = grad_constraint(msgs)
-            # server-side clip (Alg.1 l.10) fused into the aggregation:
-            # one batched norm pass + factors applied in-register by the
-            # per-chip clip_then_aggregate, never materializing the
-            # clipped message tree
-            agg = server(msgs, mask=sampled, key=k_agg,
-                         base_specs=base_specs_of(msgs),
-                         radius=lam if server.clips else None)
-            return jax.tree_util.tree_map(
-                lambda g, a: (g.astype(F32) + a.astype(F32)).astype(g.dtype),
-                state.g,
-                agg,
-            )
+                    honest = jax.vmap(compress, in_axes=(0, 0))(
+                        jnp.arange(W), diff)
+                # the in-graph omniscient attack stage: byzantine rows see
+                # the sampled honest messages of THIS round (ALIE/IPM
+                # statistics computed per leaf == per coordinate of the
+                # full message)
+                with jax.named_scope("attack"):
+                    msgs = attack_stage.corrupt_tree(
+                        honest, good_mask=~byz, sampled=sampled, key=k_att
+                    )
+                msgs = grad_constraint(msgs)
+                # server-side clip (Alg.1 l.10) fused into the aggregation:
+                # one batched norm pass + factors applied in-register by
+                # the per-chip clip_then_aggregate, never materializing the
+                # clipped message tree
+                agg = server(msgs, mask=sampled, key=k_agg,
+                             base_specs=base_specs_of(msgs),
+                             radius=lam if server.clips else None,
+                             with_factors=counting)
+                if counting:
+                    agg, factors = agg
+                with jax.named_scope("update"):
+                    g_new = jax.tree_util.tree_map(
+                        lambda g, a: (g.astype(F32)
+                                      + a.astype(F32)).astype(g.dtype),
+                        state.g,
+                        agg,
+                    )
+                if not counting:
+                    return g_new
+                # the factors the fused clip already computed
+                clipped = jnp.sum(sampled & (factors < 1.0), dtype=jnp.int32)
+                return g_new, clipped, jnp.int32(evals_old)
 
         def full_branch(_):
-            msgs = attack_stage.corrupt_tree(
-                grads_new, good_mask=~byz, sampled=sampled, key=k_att
-            )
-            msgs = grad_constraint(msgs)
-            # full-gradient rounds aggregate RAW gradients (Alg. 1): no
-            # clip even under a static-radius plan
-            return server.aggregate(msgs, mask=sampled, key=k_agg,
-                                    base_specs=base_specs_of(msgs))
+            with jax.named_scope("round_full"):
+                with jax.named_scope("attack"):
+                    msgs = attack_stage.corrupt_tree(
+                        grads_new, good_mask=~byz, sampled=sampled, key=k_att
+                    )
+                msgs = grad_constraint(msgs)
+                # full-gradient rounds aggregate RAW gradients (Alg. 1): no
+                # clip even under a static-radius plan
+                g_new = server.aggregate(msgs, mask=sampled, key=k_agg,
+                                         base_specs=base_specs_of(msgs))
+                if not counting:
+                    return g_new
+                return g_new, jnp.int32(0), jnp.int32(0)
 
-        g_new = jax.lax.cond(c_k, full_branch, diff_branch, operand=None)
+        out = jax.lax.cond(c_k, full_branch, diff_branch, operand=None)
+        stats = None
+        if counting:
+            g_new, clipped, evals_old = out
+            s = state.stats
+            byz_only = ~c_k & ~jnp.any(sampled & ~byz)
+            stats = TrainStats(
+                rounds_full=s.rounds_full + c_k.astype(jnp.int32),
+                rows_sampled=s.rows_sampled
+                + jnp.sum(sampled, dtype=jnp.int32),
+                rounds_byzantine_only=s.rounds_byzantine_only
+                + byz_only.astype(jnp.int32),
+                rows_clipped=s.rows_clipped + clipped,
+                worker_evals=s.worker_evals + evals_new + evals_old,
+            )
+        else:
+            g_new = out
         return MeshTrainState(
-            params=params_new, g=g_new, key=key, step=state.step + 1
+            params=params_new, g=g_new, key=key, step=state.step + 1,
+            stats=stats,
         )
 
     return train_step
