@@ -8,13 +8,19 @@ reduction read, a scale read+write materializing the clipped matrix, and
 the aggregation read.  The fused path streams the matrix exactly twice and
 never materializes the clipped matrix in HBM:
 
-  pass 1  (n, TILE_D) VMEM blocks -> per-row partial sum-of-squares
-          (one f32 per row per tile); host-side sqrt + min{1, lambda/norm}
-          gives the n scalar clip factors.
-  pass 2  re-streams each block, applies the per-row factors in-register,
-          and immediately runs the masked selection network (CM or
-          trimmed mean) — with ``bucket_idx`` it first permutes rows and
-          averages buckets of ``bucket_s`` in VMEM (Bucketing fusion).
+  pass 1  wide (n, width) VMEM blocks (``stream_geometry``: about
+          ``BLOCK_BYTES`` a grid step) -> per-row partial sum-of-squares
+          (one f32 per row per block, lanes past d masked off);
+          host-side sqrt + min{1, lambda/norm} gives the n scalar clip
+          factors.
+  pass 2  re-streams each block chunk by chunk (``select_stream``),
+          applies the per-row factors in-register, and immediately runs
+          the masked selection network (CM or trimmed mean).  Neither
+          pass pads the (n, d) operand: the grid is cdiv(d, width).
+          With ``bucket_idx`` pass 2 instead walks (n, TILE_D) tiles of
+          the padded matrix, first permuting rows and averaging buckets
+          of ``bucket_s`` in VMEM (Bucketing fusion); its norm pass takes
+          the same tiles.
 
 HBM traffic drops from ~4*n*d to ~2*n*d streamed words.  Setting
 ``use_clip=False`` skips pass 1 entirely (plain kernel aggregation for the
@@ -35,7 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .coordinate_median import (TILE_D, _pad_to, _select_masked,
-                                store_tile_partial, tile_partials)
+                                chunk_offset, for_chunks, store_tile_partial,
+                                stream_geometry, stream_rows, tile_partials)
 
 F32 = jnp.float32
 _BIG = 3.4e37
@@ -51,18 +58,18 @@ def clip_factor(norm, radius):
     return jnp.minimum(1.0, radius / jnp.maximum(norm, _EPS))
 
 
-def _rownorm_kernel(x_ref, o_ref):
-    x = x_ref[...].astype(F32)  # (n, td)
-    store_tile_partial(o_ref, jnp.sum(x * x, axis=1, keepdims=True))
+def _rownorm_kernel(x_ref, o_ref, *, d, chunk):
+    n, width = x_ref.shape
+    base = pl.program_id(0) * width
 
+    def body(c, acc):
+        off = chunk_offset(c, chunk)
+        x = x_ref[:, pl.ds(off, chunk)].astype(F32)  # (n, chunk)
+        lane = base + off + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        return acc + jnp.where(lane < d, x * x, 0.0)
 
-def _clip_agg_kernel(factor_ref, mask_ref, x_ref, o_ref, *, trim_ratio):
-    x = x_ref[...].astype(F32)  # (n, td)
-    f = factor_ref[...].astype(F32)  # (n, 1)
-    m = mask_ref[...].astype(F32)  # (n, 1)
-    vals = jnp.where(m > 0.5, x * f, _BIG)
-    out = _select_masked(vals, m, trim_ratio=trim_ratio)
-    o_ref[...] = out.astype(o_ref.dtype)
+    acc = for_chunks(width // chunk, body, jnp.zeros((n, chunk), F32))
+    store_tile_partial(o_ref, jnp.sum(acc, axis=1, keepdims=True))
 
 
 def _clip_bucket_agg_kernel(
@@ -86,15 +93,21 @@ def _clip_bucket_agg_kernel(
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _row_norms(xp, grid, n, interpret, reduce_fn=None):
-    """Per-row l2 norms via tile-partial sums of squares.  ``reduce_fn``
-    (e.g. a psum over shard_map axes) turns block-local partial sums into
-    global ones when ``xp`` is one coordinate shard of a larger row."""
+def _row_norms(xp, grid, n, interpret, reduce_fn=None, width=TILE_D):
+    """Per-row l2 norms via block-partial sums of squares over ``grid``
+    (n, width) blocks of ``xp`` (the last may run past its end: those lanes
+    count 0).  ``reduce_fn`` (e.g. a psum over shard_map axes) turns
+    block-local partial sums into global ones when ``xp`` is one coordinate
+    shard of a larger row."""
     out_spec, out_shape = tile_partials(n, grid)
+    # a block wider than a tile is a whole number of tiles; a narrower one
+    # is the whole leaf
+    kernel = functools.partial(_rownorm_kernel, d=xp.shape[1],
+                               chunk=min(width, TILE_D))
     partial_ssq = pl.pallas_call(
-        _rownorm_kernel,
+        kernel,
         grid=(grid,),
-        in_specs=[pl.BlockSpec((n, TILE_D), lambda i: (0, i))],
+        in_specs=[pl.BlockSpec((n, width), lambda i: (0, i))],
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
@@ -146,72 +159,59 @@ def clip_then_aggregate(
     if mask is None:
         mask = jnp.ones((n,), jnp.float32)
     mask = mask.astype(jnp.float32)
+
+    def clip_factors(xp, width):
+        """(factors (n,), norms (n,) or None) over (n, width) blocks."""
+        if not use_clip:
+            return jnp.ones((n,), F32), None
+        if factors is not None:
+            return factors.astype(F32), None
+        grid = pl.cdiv(xp.shape[1], width)
+        norms = _row_norms(xp, grid, n, interpret, reduce_fn, width)
+        return clip_factor(norms, radius).astype(F32), norms
+
+    if bucket_s < 2:
+        f, norms = clip_factors(xs, stream_geometry(n, d, xs.dtype.itemsize)[0])
+        out = stream_rows(xs, mask, f, trim_ratio=trim_ratio,
+                          name="clip_aggregate", interpret=interpret)
+        return out, norms
+
+    # Bucketing walks (n, TILE_D) tiles of the padded matrix
     xp, pad = _pad_to(xs, TILE_D, axis=1)
-    dp = xp.shape[1]
-    grid = dp // TILE_D
-
-    if use_clip:
-        if factors is None:
-            norms = _row_norms(xp, grid, n, interpret, reduce_fn)
-            factors = clip_factor(norms, radius).astype(F32)
-        else:
-            norms = None
-            factors = factors.astype(F32)
-    else:
-        norms = None
-        factors = jnp.ones((n,), F32)
-
-    if bucket_s >= 2:
-        if bucket_idx is None:
-            bucket_idx = jnp.arange(n, dtype=jnp.int32)
-        pad_rows = (-n) % bucket_s
-        n_p = n + pad_rows
-        if pad_rows:
-            # Padded rows are zero with mask 0; padded idx entries point at
-            # them, matching aggregators._bucketing (permute then pad).
-            xp = jnp.pad(xp, ((0, pad_rows), (0, 0)))
-            mask = jnp.pad(mask, (0, pad_rows))
-            factors = jnp.pad(factors, (0, pad_rows), constant_values=1.0)
-            bucket_idx = jnp.concatenate(
-                [
-                    bucket_idx.astype(jnp.int32),
-                    jnp.arange(n, n_p, dtype=jnp.int32),
-                ]
-            )
-        kernel = functools.partial(
-            _clip_bucket_agg_kernel, s=bucket_s, trim_ratio=trim_ratio
+    f, norms = clip_factors(xp, TILE_D)
+    grid = xp.shape[1] // TILE_D
+    if bucket_idx is None:
+        bucket_idx = jnp.arange(n, dtype=jnp.int32)
+    pad_rows = (-n) % bucket_s
+    n_p = n + pad_rows
+    if pad_rows:
+        # Padded rows are zero with mask 0; padded idx entries point at
+        # them, matching aggregators._bucketing (permute then pad).
+        xp = jnp.pad(xp, ((0, pad_rows), (0, 0)))
+        mask = jnp.pad(mask, (0, pad_rows))
+        f = jnp.pad(f, (0, pad_rows), constant_values=1.0)
+        bucket_idx = jnp.concatenate(
+            [
+                bucket_idx.astype(jnp.int32),
+                jnp.arange(n, n_p, dtype=jnp.int32),
+            ]
         )
-        name = "clip_bucket_aggregate"
-        in_specs = [
+    out = pl.pallas_call(
+        functools.partial(
+            _clip_bucket_agg_kernel, s=bucket_s, trim_ratio=trim_ratio
+        ),
+        grid=(grid,),
+        in_specs=[
             pl.BlockSpec((n_p, 1), lambda i: (0, 0)),  # idx: resident
             pl.BlockSpec((n_p, 1), lambda i: (0, 0)),  # factors: resident
             pl.BlockSpec((n_p, 1), lambda i: (0, 0)),  # mask: resident
             pl.BlockSpec((n_p, TILE_D), lambda i: (0, i)),
-        ]
-        operands = (
-            bucket_idx.reshape(n_p, 1),
-            factors.reshape(n_p, 1),
-            mask.reshape(n_p, 1),
-            xp,
-        )
-    else:
-        kernel = functools.partial(_clip_agg_kernel, trim_ratio=trim_ratio)
-        name = "clip_aggregate"
-        in_specs = [
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),  # factors: resident
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),  # mask: resident
-            pl.BlockSpec((n, TILE_D), lambda i: (0, i)),
-        ]
-        operands = (factors.reshape(n, 1), mask.reshape(n, 1), xp)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=in_specs,
+        ],
         out_specs=pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, dp), xs.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, xp.shape[1]), xs.dtype),
         interpret=interpret,
-        name=name,
-    )(*operands)
+        name="clip_bucket_aggregate",
+    )(bucket_idx.reshape(n_p, 1), f.reshape(n_p, 1),
+      mask.reshape(n_p, 1), xp)
     out = out[0]
     return (out[:d] if pad else out), norms

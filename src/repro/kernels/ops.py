@@ -23,8 +23,8 @@ plan-level backend contract):
   rule               plain kernel    fused clip->aggregate  Bucketing
   =================  ==============  =====================  ============
   cm / trimmed_mean  selection net   2-stream 2-pass        resident
-                     (CM/TM tiles)   (clip_aggregate.py)    row-gather
-  mean               TM(t=0) tiles   same 2-stream kernel   row-gather
+                     (wide blocks)   (clip_aggregate.py)    row-gather
+  mean               TM(t=0) blocks  same 2-stream kernel   row-gather
   krum / multi_krum  MXU Gram tile   2 streams: Gram pass   Gram algebra
                      (krum.py)       (factors = f(diag G),  M G M^T
                                      G_c = ff^T o G) +

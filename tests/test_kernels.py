@@ -259,3 +259,151 @@ def test_kernel_platform_fallbacks_only_on_cpu(monkeypatch, platform,
     else:
         assert ops._interpret() is interpret
         assert resolve_backend("auto") == auto
+
+
+# ---- wide-block streaming geometry (coordinate_median / clip_aggregate) --
+
+def _tile_oracle(xs, mask, factors, trim_ratio):
+    """The (n, TILE_D)-tile kernels' arithmetic over the whole matrix: rows
+    on sublanes, ranks by index tie-break, the pick summed over rows.  The
+    ops are coordinate-wise, so this is the 512-lane-tile result."""
+    from repro.kernels.coordinate_median import _BIG, _select_masked
+
+    m = mask.astype(jnp.float32).reshape(-1, 1)
+    x = xs.astype(jnp.float32)
+    if factors is not None:
+        x = x * factors.astype(jnp.float32).reshape(-1, 1)
+    vals = jnp.where(m > 0.5, x, _BIG)
+    return _select_masked(vals, m, trim_ratio=trim_ratio)[0].astype(xs.dtype)
+
+
+def _stream_d(n, spec):
+    """A coordinate count, or one at the block width of (n, bf16) rows."""
+    from repro.kernels.coordinate_median import stream_geometry
+
+    if isinstance(spec, int):
+        return spec
+    width = stream_geometry(n, 1 << 30, 2)[0]
+    return {"width-128": width - 128, "width": width, "width+1": width + 1,
+            "width+128": width + 128}[spec]
+
+
+@pytest.mark.parametrize(
+    "d",
+    [1, 48, 130, 3328, 3328 * 4 + 48, "width-128", "width", "width+1",
+     "width+128"],
+    ids=str,
+)
+@pytest.mark.parametrize("n", [1, 4, 8, 64])
+@pytest.mark.parametrize(
+    "op", ["cm", "cm_masked", "trimmed_mean", "clip_factors", "clip_norms"]
+)
+def test_stream_kernels_match_ref_and_tile_result(op, n, d):
+    """Wide blocks with a partial last block (no pad) give the reference's
+    aggregate and, bit for bit, the 512-lane-tile kernels' selection; the
+    norm pass's row norms differ from the reference in reduction order
+    only."""
+    from repro.kernels import clip_then_aggregate
+    from repro.kernels.clip_aggregate import clip_factor
+    from repro.kernels.ref import clip_then_aggregate_ref
+
+    d = _stream_d(n, d)
+    rng = np.random.RandomState(n * 1009 + d % 997)
+    xs = jnp.asarray(rng.randn(n, d), jnp.bfloat16)
+    mask = np.ones(n, np.float32)
+    if op == "cm_masked":
+        # one of four rows sampled (all of one row where n == 1)
+        mask = (np.arange(n) % 4 == 1 % n).astype(np.float32)
+    mask = jnp.asarray(mask)
+    factors = None
+    tol = _tol(jnp.bfloat16)
+    trim = 0.25 if op == "trimmed_mean" else -1.0
+    if op in ("cm", "cm_masked"):
+        out = coordinate_median(xs, mask)
+        ref = coordinate_median_ref(xs, mask > 0.5)
+    elif op == "trimmed_mean":
+        out = trimmed_mean(xs, mask, trim_ratio=trim)
+        ref = trimmed_mean_ref(xs, mask > 0.5, trim_ratio=trim)
+    elif op == "clip_factors":
+        factors = jnp.asarray(rng.uniform(0.3, 1.0, n), jnp.float32)
+        out, norms = clip_then_aggregate(xs, 1.0, mask, factors=factors)
+        assert norms is None
+        ref = coordinate_median_ref(
+            (xs.astype(jnp.float32) * factors[:, None]).astype(jnp.bfloat16),
+            mask > 0.5,
+        )
+    else:
+        radius = 0.5 * float(np.sqrt(d))
+        out, norms = clip_then_aggregate(xs, radius, mask)
+        ref, rnorms = clip_then_aggregate_ref(xs, radius, mask > 0.5)
+        np.testing.assert_allclose(
+            np.asarray(norms), np.asarray(rnorms), rtol=1e-5
+        )
+        factors = clip_factor(norms, radius)
+    assert out.shape == (d,) and out.dtype == xs.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32), **tol
+    )
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(_tile_oracle(xs, mask, factors, trim), np.float32),
+    )
+
+
+@pytest.mark.parametrize(
+    "d", [77_230_080, 39_616_512], ids=["embedding", "in_proj"]
+)
+@pytest.mark.parametrize("call", ["clip_factors", "clip_norms", "cm"])
+def test_stream_kernels_grid_is_wide_and_pad_free(call, d):
+    """At the trainer's largest leaves (n = 4 bf16 rows of mamba2-780m's
+    (vocab, d_model) embedding and a (4, 1536, 6448) in_proj) each
+    streaming kernel runs at most 2,048 grid steps, where 512-lane tiles
+    took 150,840 and 77,376, and nothing pads the (n, d) operand."""
+    import math
+
+    from _jaxpr_utils import iter_eqns_outside_kernels, pallas_calls
+
+    from repro.kernels import clip_then_aggregate
+
+    n = 4
+    x = jax.ShapeDtypeStruct((n, d), jnp.bfloat16)
+    rows = jax.ShapeDtypeStruct((n,), jnp.float32)
+    if call == "cm":
+        fn, names = (lambda x, m, f: coordinate_median(x, m)), [
+            "coordinate_median"]
+    elif call == "clip_factors":
+        fn, names = (lambda x, m, f: clip_then_aggregate(
+            x, 1.0, m, factors=f)), ["clip_aggregate"]
+    else:
+        fn, names = (lambda x, m, f: clip_then_aggregate(x, 1.0, m)), [
+            "clip_row_norms", "clip_aggregate"]
+    jaxpr = jax.make_jaxpr(fn)(x, rows, rows).jaxpr
+    for name in names:
+        (eqn,) = pallas_calls(jaxpr, name)
+        steps = math.prod(eqn.params["grid_mapping"].grid)
+        assert steps <= 2048, (name, steps)
+    assert not [
+        e for e in iter_eqns_outside_kernels(jaxpr)
+        if e.primitive.name in ("pad", "concatenate")
+    ]
+
+
+@pytest.mark.parametrize("n", [2, 3, 5, 6, 7])
+def test_dense_median_every_row_count(n):
+    """The dense (n, 64, 128) layout's median at every other n below 8,
+    with all, some and one row sampled, over two blocks and a partial
+    third."""
+    from repro.kernels import clip_then_aggregate
+    from repro.kernels.coordinate_median import stream_geometry
+
+    d = 2 * stream_geometry(n, 1 << 30, 2)[0] + 128
+    rng = np.random.RandomState(40 + n)
+    xs = jnp.asarray(rng.randn(n, d), jnp.bfloat16)
+    factors = jnp.asarray(rng.uniform(0.3, 1.0, n), jnp.float32)
+    for mask in (np.ones(n), rng.rand(n) > 0.5, np.arange(n) == n - 1):
+        mask = jnp.asarray(mask, jnp.float32)
+        out, _ = clip_then_aggregate(xs, 1.0, mask, factors=factors)
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32),
+            np.asarray(_tile_oracle(xs, mask, factors, -1.0), np.float32),
+        )

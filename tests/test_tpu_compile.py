@@ -23,6 +23,7 @@ from repro.kernels.centered_clip import (MAX_VMEM_ELEMS,
                                         resident_elems)
 from repro.kernels.clip_aggregate import clip_then_aggregate
 from repro.kernels.clipped_diff import clipped_diff
+from repro.kernels.coordinate_median import coordinate_median
 from repro.kernels.geometric_median import clip_then_geometric_median
 from repro.kernels.krum import clip_then_krum, cross_gram
 
@@ -33,6 +34,10 @@ _CFG = get_config("mamba2-780m")
 D_TRAIN = _CFG.vocab * _CFG.d_model
 # the message rows chip_smoke.py's aggregation-server phase ingests (f32)
 D_SERVE = 1 << 22
+# coordinate counts that are no multiple of 128: the streaming kernels'
+# partial last block (a block wider than the whole leaf at n = 4 and 8),
+# and a leaf of under one tile (mamba2's (layers, heads) float32 A_log)
+RAGGED = ((3328 * 4 + 48, jnp.bfloat16), (4 * 48, jnp.float32))
 COHORTS = (4, 8, 64)
 
 
@@ -79,6 +84,8 @@ _TRAIN_KERNELS = {
         x, r, m),
     "trimmed_mean": lambda x, r, m, f: clip_then_aggregate(
         x, r, m, None, f, trim_ratio=0.25),
+    # the full rounds' plain median
+    "coordinate_median": lambda x, r, m, f: coordinate_median(x, m),
     # Gram + the winner-row select pass
     "krum": lambda x, r, m, f: clip_then_krum(x, r, m, byz_bound=1),
     # Gram + the weighted row-sum pass
@@ -104,6 +111,22 @@ def test_train_kernel_compiles_at_message_size(one_chip, kernel, n):
     """Each fused kernel on an (n, D_TRAIN) bf16 message block."""
     _assert_kernel(_compile(
         _TRAIN_KERNELS[kernel], *_row_args(one_chip, n, D_TRAIN, jnp.bfloat16)
+    ))
+
+
+@pytest.mark.parametrize("d,dtype", RAGGED, ids=["d13360_bf16", "d192_f32"])
+@pytest.mark.parametrize("n", COHORTS)
+@pytest.mark.parametrize(
+    "kernel",
+    ["cm_clip_factors", "cm_clip_norms", "trimmed_mean", "coordinate_median"],
+)
+def test_stream_kernel_compiles_with_partial_block(one_chip, kernel, n, d,
+                                                   dtype):
+    """The wide-block kernels on a ragged d: the partial last block, the
+    norm pass's lane mask, a one-chunk block read at a static offset, and
+    the VMEM footprint at every cohort size."""
+    _assert_kernel(_compile(
+        _TRAIN_KERNELS[kernel], *_row_args(one_chip, n, d, dtype)
     ))
 
 
