@@ -46,10 +46,15 @@ def read_metric(name, run, root=HERE):
     return mod.read(run)
 
 
+def layers(kind, root=HERE):
+    """{name: layer} from ``<kind>/<name>.json``."""
+    return {p.stem: _json(p)["layer"]
+            for p in sorted((Path(root) / kind).glob("*.json"))}
+
+
 def kernel_layers(root=HERE):
     """{kernel name: layer} from ``kernels/<kernel>.json``."""
-    return {p.stem: _json(p)["layer"]
-            for p in sorted((Path(root) / "kernels").glob("*.json"))}
+    return layers("kernels", root)
 
 
 def peaks(device_kind, root=HERE):

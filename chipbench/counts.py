@@ -2,10 +2,9 @@
 configuration's shapes (never from the compiler or the program).
 
 Model FLOPs count one forward pass as 2 x multiply-adds of every matmul a
-token needs, with the causal intra-chunk SSD term counted over the (Q+1)/2
-positions a token sees on average; a
-training evaluation is 3 forward passes (forward and backward), and
-rematerialized recompute is not counted.
+token needs, as each family's reference module counts them
+(``forward_flops_per_token``); a training evaluation is 3 forward passes
+(forward and backward), and rematerialized recompute is not counted.
 """
 import jax
 import jax.numpy as jnp
@@ -13,24 +12,9 @@ import jax.numpy as jnp
 from .reference import model_module
 
 
-def mamba2_layer_flops(m, seq):
-    d, N, K = m["d_model"], m["ssm_state"], m["ssm_conv"]
-    di = m["ssm_expand"] * d
-    nh = di // m["ssm_head_dim"]
-    Q = min(m["ssm_chunk"], seq)
-    proj = 2 * d * (2 * di + 2 * N + nh) + 2 * di * d
-    conv = 2 * K * (di + 2 * N)
-    ssd = (Q + 1) * (N + di) + 4 * N * di
-    return proj + conv + ssd
-
-
-LAYER_FLOPS = {"mamba2": mamba2_layer_flops}
-
-
 def forward_flops_per_token(config, seq):
-    m = config["model"]
-    layer = LAYER_FLOPS[config["family"]](m, seq)
-    return m["n_layers"] * layer + 2 * m["d_model"] * m["vocab"]
+    return model_module(config["family"]).forward_flops_per_token(
+        config["model"], seq)
 
 
 def required_evaluations(cell):

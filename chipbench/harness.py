@@ -9,7 +9,14 @@ object step after step, each ended with ``block_until_ready`` before the
 next is dispatched.  Once the window has closed and the device memory has
 been read, the program's state is freed and the plain reference follows
 the first two steps from the same weights, g^0 and batches.
+
+A traced run builds the step with the program's work counters in its state
+(``TrainStats``), so its first steps and its window drive that one
+program, and the untraced runs compile and time the program as it is by
+default; it reduces the window's trace with each op's name scope
+(``scoped.reduce``).
 """
+import contextlib
 import gc
 import json
 import os
@@ -20,7 +27,7 @@ import time
 
 import numpy as np
 
-from . import cells, compare, feed, tracing
+from . import cells, compare, feed, scoped
 from .reference import algorithm, model_module
 from .reference.common import identity
 
@@ -36,15 +43,6 @@ def memory_in_use(devices):
     return {k: v for k, v in (devices[0].memory_stats() or {}).items()
             if k in ("bytes_in_use", "bytes_reserved", "peak_bytes_in_use",
                      "peak_bytes_reserved")}
-
-
-def process_age():
-    """Seconds since this process started (Linux)."""
-    with open("/proc/self/stat") as f:
-        start = int(f.read().rsplit(")", 1)[1].split()[19])
-    with open("/proc/uptime") as f:
-        up = float(f.read().split()[0])
-    return up - start / os.sysconf("SC_CLK_TCK")
 
 
 def import_program():
@@ -88,6 +86,27 @@ def chips_or_exit(n):
     return devs[:n]
 
 
+@contextlib.contextmanager
+def cache_lookups():
+    """Counts the persistent compilation cache's lookups and hits inside
+    the block (none where the cache is off)."""
+    import jax
+
+    counts = {"requests": 0, "hits": 0}
+
+    def listen(event, **_):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            counts["requests"] += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            counts["hits"] += 1
+
+    jax.monitoring.register_event_listener(listen)
+    try:
+        yield counts
+    finally:
+        jax.monitoring.unregister_event_listener(listen)
+
+
 def _leaf_norm_fns():
     import jax
     import jax.numpy as jnp
@@ -109,9 +128,12 @@ def _leaf_norm_fns():
 class Trainer:
     """The program's compiled step and its state, after the first steps.
 
-    ``wrap`` plants a fault under the timed path (see ``faults``)."""
+    ``wrap`` plants a fault under the timed path (see ``faults``);
+    ``counters`` starts the state on the program's work counters from
+    zero (``scoped.counters`` reads them)."""
 
-    def __init__(self, cell, config, seed, devices, wrap=None):
+    def __init__(self, cell, config, seed, devices, wrap=None,
+                 counters=False):
         import jax
         import jax.numpy as jnp
         from jax.sharding import AxisType
@@ -120,7 +142,7 @@ class Trainer:
             ServerPlan
         from repro.configs.registry import get_config
         from repro.launch.train import ByzTrainConfig, MeshTrainState, \
-            make_train_step
+            init_train_stats, make_train_step
         from repro.models.model import init_params
 
         W = cell["workers"]
@@ -165,12 +187,16 @@ class Trainer:
             state = MeshTrainState(
                 params=x0, g=g0,
                 key=jax.random.PRNGKey(cell["algorithm_key"]),
-                step=jnp.int32(0))
+                step=jnp.int32(0),
+                stats=init_train_stats() if counters else None)
             t0 = time.perf_counter()
-            self.compiled = jax.jit(step_fn, donate_argnums=0).lower(
-                state, self.feeds[0]).compile()
+            with cache_lookups() as lookups:
+                self.compiled = jax.jit(step_fn, donate_argnums=0).lower(
+                    state, self.feeds[0]).compile()
+            self.compiled_here = lookups["requests"] > lookups["hits"]
             self.memory = self.compiled.memory_analysis()
-            log(f"step compiled or loaded in {time.perf_counter() - t0:.3f} s;"
+            log(f"step {'compiled' if self.compiled_here else 'loaded'} in "
+                f"{time.perf_counter() - t0:.3f} s;"
                 f" arguments {self.memory.argument_size_in_bytes} B, "
                 f"temporaries {self.memory.temp_size_in_bytes} B, peak "
                 f"{self.memory.peak_memory_in_bytes} B")
@@ -268,15 +294,27 @@ def reference(cell, config, seed, tokens, *, control=False,
 
 
 def run_cell(cell, config, seed, seconds, trace, devices, *, bench,
-             age_at_start, t_start, wrap=None):
-    """One run; returns (result dict, check lines)."""
+             age_at_start, t_start, wrap=None, stop_if_compiled=False):
+    """One run; returns (result dict, check lines).  With
+    ``stop_if_compiled``, a run that had to compile the step stops once
+    its set-up has filled the compilation cache, and returns (None, [])."""
     import jax
 
-    trainer = Trainer(cell, config, seed, devices, wrap=wrap)
+    trainer = Trainer(cell, config, seed, devices, wrap=wrap,
+                      counters=trace)
+    if stop_if_compiled and trainer.compiled_here:
+        with jax.set_mesh(trainer.mesh):
+            trainer.finite()
+        log("the step was compiled in this process: set-up done and "
+            "cached, no window")
+        return None, []
     setup_s = age_at_start + (time.perf_counter() - t_start)
     log(f"set-up {setup_s:.3f} s")
-    trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace \
-        else None
+    trace_dir, op_paths, counted = None, {}, None
+    if trace:
+        counted = scoped.counters(trainer.state)
+        op_paths = scoped.op_paths(trainer.compiled.as_text())
+        trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
     try:
         load0 = host_load()
         with jax.set_mesh(trainer.mesh):
@@ -291,6 +329,9 @@ def run_cell(cell, config, seed, seconds, trace, devices, *, bench,
             f"CPU s, {load1['threads']} threads, {throttled}; slowest steps "
             + ", ".join(f"{i}: {times[i]:.4f} s" for i in slow))
         peak = memory_peak_bytes(devices)
+        if trace:
+            counted = {k: v - counted[k] for k, v in
+                       scoped.counters(trainer.state).items()}
         numbers, moved, paths = trainer.numbers, trainer.moved, \
             trainer.paths
         tokens = np.asarray(trainer.tokens[:COMPARED_STEPS])
@@ -302,7 +343,11 @@ def run_cell(cell, config, seed, seconds, trace, devices, *, bench,
         if trace:
             xplanes = sorted(p for p in _walk(trace_dir)
                              if p.endswith(".xplane.pb"))
-            reduced = tracing.reduce(tracing.load(xplanes[-1]))
+            reduced = scoped.reduce(scoped.load(xplanes[-1]),
+                                    paths=op_paths)
+            log("device time a step by innermost name scope (ms): "
+                + ", ".join(f"{k} {1e3 * v / reduced['steps']:.3f}"
+                            for k, v in sorted(reduced["scope_s"].items())))
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -318,7 +363,7 @@ def run_cell(cell, config, seed, seconds, trace, devices, *, bench,
         "steps": len(times), "window_s": window_s, "step_times": times,
         "tokens_per_step": (cell["workers"] * cell["per_worker_batch"]
                             * cell["seq"]),
-        "memory_peak_bytes": peak, "trace": reduced,
+        "memory_peak_bytes": peak, "trace": reduced, "counters": counted,
     }
     metrics = {}
     for name, unit in cells.metric_entries(bench, cell["name"], trace):

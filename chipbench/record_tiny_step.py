@@ -6,9 +6,9 @@ tests of the trace reduction read, on one TPU chip.
 
 A tiny Mamba-2 (2 layers, d_model 64, vocabulary 256) under cell 2's
 composition (W = 4, C = 1, ALIE, coordinate median behind the clip, naive
-placement, Pallas), p = 1/2; three steps set it up, then the work
-counters are turned on and three more steps run under the profiler inside
-the harness's host spans.
+placement, Pallas), p = 1/2, with the work counters in its state; three
+steps set it up, then three more run under the profiler inside the
+harness's host spans.
 Writes ``tiny_step_v5e.xplane.pb.gz`` and the compiled step's text
 ``tiny_step_v5e.hlo.txt.gz`` (default: ``chipbench/testdata/``) and prints
 the reduction.
@@ -40,14 +40,7 @@ def main(out=cells.HERE / "testdata"):
     devices = harness.chips_or_exit(1)
     cell, _ = cells.load_cell("mamba2_780m.pp_w4c1_s512")
     cell = dict(cell, seq=64, batches=8, p=0.5, algorithm_key=KEY)
-    kept = {}
-
-    def keep(step_fn, W):
-        kept["step"] = step_fn
-        return step_fn
-
-    trainer = harness.Trainer(cell, TINY, SEED, devices, wrap=keep)
-    scoped.with_counters(trainer, kept["step"])
+    trainer = harness.Trainer(cell, TINY, SEED, devices, counters=True)
     trace_dir = tempfile.mkdtemp(prefix="chipbench-tiny-")
     Ann = jax.profiler.TraceAnnotation
     try:

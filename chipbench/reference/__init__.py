@@ -2,8 +2,9 @@
 
 Nothing here imports the program under test.  Each model module offers
 ``init(key, model, dtype)`` (the benchmark's weights, in the program's
-parameter layout) and ``loss(params, tokens, model, q)`` (mean next-token
-cross-entropy in float32).  ``q`` rounds every matmul operand: the identity
+parameter layout), ``loss(params, tokens, model, q)`` (mean next-token
+cross-entropy in float32) and ``forward_flops_per_token(model, seq)``
+(what ``counts`` divides by).  ``q`` rounds every matmul operand: the identity
 for the reference, a lower precision for the control.
 """
 import importlib

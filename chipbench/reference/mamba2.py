@@ -108,6 +108,24 @@ def mixer(q, p, x, m):
     return mm(q, y, p["out_proj"])
 
 
+def layer_flops(m, seq):
+    """Forward FLOPs a token needs in one block: the projections, the conv,
+    and the SSD scan with its causal intra-chunk term over the (Q+1)/2
+    positions a token sees on average in a chunk of Q."""
+    d, N, K = m["d_model"], m["ssm_state"], m["ssm_conv"]
+    di, nh = dims(m)
+    Q = min(m["ssm_chunk"], seq)
+    proj = 2 * d * (2 * di + 2 * N + nh) + 2 * di * d
+    conv = 2 * K * (di + 2 * N)
+    ssd = (Q + 1) * (N + di) + 4 * N * di
+    return proj + conv + ssd
+
+
+def forward_flops_per_token(m, seq):
+    """Every block and the untied output projection."""
+    return m["n_layers"] * layer_flops(m, seq) + 2 * m["d_model"] * m["vocab"]
+
+
 def loss(params, tokens, m, q):
     with jax.default_matmul_precision("highest"):
         x = jnp.take(params["embed"], tokens, axis=0)

@@ -2,21 +2,26 @@
 name scopes, its work counters, and the runtime's ``run_id`` events.
 
 ``tracing.reduce`` gives the benchmark's per-step device numbers from the
-trace alone.  This module reads what the program adds beside them:
+trace alone.  This module reads what the program adds beside them, and is
+what a traced run of the harness reduces its trace with:
 
-- ``load(xplane)`` keeps what ``tracing.load`` keeps, with each module's
-  ``run_id``, the host events of every thread, and per ``run_id`` the
-  runtime's ``DoEnqueueProgram`` and ``CompleteCallbacks`` events;
+- ``load(xplane)`` keeps, per TPU device, the ops of its "XLA Ops" line
+  and its "XLA Modules" line with each module's ``run_id``; the
+  harness's own host spans (``bench.*``); the host events of every
+  thread; and per ``run_id`` the runtime's ``DoEnqueueProgram`` and
+  ``CompleteCallbacks`` events;
 - ``reduce(events, paths=op_paths(compiled.as_text()))`` is
   ``tracing.reduce`` (every number of it unchanged) plus busy time per
-  innermost name scope (``scope_s``, summing to busy time) and the part of
-  it reverse-mode AD derived (``backward_s``), each module's round kind
-  (``modules``), the ``run_id`` clock bracket (``clock``), and the idle
-  gaps labelled ``in_step`` (inside a module), ``launch`` (after the next
-  module's enqueue) or ``host:<event>`` (before it, named by the host
-  event the host was in);
-- ``with_counters(trainer, step_fn)`` starts a ``harness.Trainer``'s state
-  on the program's ``TrainStats`` and ``counters(state)`` reads them;
+  innermost name scope (``scope_s``, summing to busy time) and per scope
+  anywhere on an op's path (``within_s``, which a scope nested inside
+  another later leaves as it was), the part of ``scope_s`` reverse-mode AD
+  derived (``backward_s``), each module's round kind (``modules``), the
+  ``run_id`` clock bracket (``clock``), and the longest idle gaps
+  (``idle_gaps``) labelled ``in_step`` (inside a module), ``launch``
+  (after the next module's enqueue) or ``host:<event>`` (before it, named
+  by the host event the host was in);
+- ``counters(state)`` reads the program's ``TrainStats`` from a state
+  started on them (``harness.Trainer(..., counters=True)``);
 - ``layer_metrics`` turns a reduction and the counters' change over a
   window into the per-layer numbers they give;
 - ``program_digest`` hashes a compiled program without what only says
@@ -31,12 +36,10 @@ later than the runtime sees it complete.
 import base64
 import gzip
 import hashlib
-import json
 import re
 import statistics
 
-from . import tracing
-from .cells import HERE
+from . import cells, tracing
 
 ENQUEUE, COMPLETE = "DoEnqueueProgram", "CompleteCallbacks"
 UNSCOPED = "unscoped"
@@ -53,10 +56,9 @@ _FRAME_TABLE = re.compile(
 _KERNEL_BODY = re.compile(r'"body":"([^"]*)"')
 
 
-def scope_layers(root=HERE):
-    """{name scope of the program: layer} from ``scopes.json``."""
-    with open(root / "scopes.json") as f:
-        return json.load(f)
+def scope_layers(root=cells.HERE):
+    """{name scope of the program: layer} from ``scopes/<scope>.json``."""
+    return cells.layers("scopes", root)
 
 
 def _profile(path):
@@ -244,10 +246,12 @@ def gap_label(gap, modules, events, offset):
 
 
 def scope_times(leaves, lo, hi, paths, scopes):
-    """({scope: busy ns}, {scope: busy ns of ops AD derived}) over [lo,
-    hi): each instant of the union of the leaf ops' intervals goes to the
-    op that covers it first, so the times sum to the busy time."""
-    times, backward = {}, {}
+    """({innermost scope: busy ns}, {scope anywhere on the path: busy ns},
+    {innermost scope: busy ns of ops AD derived}) over [lo, hi): each
+    instant of the union of the leaf ops' intervals goes to the op that
+    covers it first, so the innermost scopes' times sum to the busy
+    time."""
+    times, within, backward = {}, {}, {}
     covered = lo
     for o in sorted(leaves, key=lambda o: o[1]):
         a, b = max(o[1], covered, lo), min(o[2], hi)
@@ -255,11 +259,13 @@ def scope_times(leaves, lo, hi, paths, scopes):
             continue
         covered = b
         path = paths.get(o[0])
-        scope, _ = scope_of(path, scopes)
+        scope, found = scope_of(path, scopes)
         times[scope] = times.get(scope, 0) + b - a
+        for s in found:
+            within[s] = within.get(s, 0) + b - a
         if path and BACKWARD in path:
             backward[scope] = backward.get(scope, 0) + b - a
-    return times, backward
+    return times, within, backward
 
 
 def module_kinds(modules, ops, lo, hi, paths):
@@ -288,7 +294,7 @@ def reduce(events, kernels=None, paths=None, scopes=None):
     and labelled idle gaps, averaged over the devices of the trace.
 
     ``paths`` ({instruction name: op_name}, ``op_paths``) gives each op
-    its name scope, among ``scopes`` (``scopes.json``); an op it does not
+    its name scope, among ``scopes`` (``scopes/``); an op it does not
     name is ``unscoped``."""
     out = tracing.reduce(events, kernels)
     scopes = scope_layers() if scopes is None else scopes
@@ -297,7 +303,7 @@ def reduce(events, kernels=None, paths=None, scopes=None):
     blocks = [s for s in events["host"] if s[0] == "bench.block"]
     w0, w1 = steps[0][1], blocks[-1][2]
     n_dev = out["devices"]
-    out.update(scope_s={}, backward_s={}, modules=[], clock=[])
+    out.update(scope_s={}, within_s={}, backward_s={}, modules=[], clock=[])
     gaps = []
     for dev in events["devices"].values():
         c = tracing.clock_offset(dev["modules"], steps)
@@ -306,8 +312,9 @@ def reduce(events, kernels=None, paths=None, scopes=None):
             [o for o in dev["ops"] if o[2] > o[1]]) if leaf]
         busy = tracing.union(tracing.clip_to([[o[1], o[2]] for o in leaves],
                                              lo, hi))
-        times, backward = scope_times(leaves, lo, hi, paths, scopes)
+        times, within, backward = scope_times(leaves, lo, hi, paths, scopes)
         for into, part in ((out["scope_s"], times),
+                           (out["within_s"], within),
                            (out["backward_s"], backward)):
             for k, ns in part.items():
                 into[k] = into.get(k, 0.0) + ns * 1e-9 / n_dev
@@ -326,20 +333,6 @@ def reduce(events, kernels=None, paths=None, scopes=None):
     return out
 
 
-def with_counters(trainer, step_fn):
-    """Start ``trainer``'s state (a ``harness.Trainer`` built with a
-    ``wrap`` that kept its ``step_fn``) on the program's work counters
-    from zero, and compile its step for that state."""
-    import jax
-
-    from repro.launch.train import init_train_stats
-
-    with jax.set_mesh(trainer.mesh):
-        trainer.state = trainer.state._replace(stats=init_train_stats())
-        trainer.compiled = jax.jit(step_fn, donate_argnums=0).lower(
-            trainer.state, trainer.feeds[0]).compile()
-
-
 def counters(state):
     """{counter: count so far}, or None where the state counts nothing."""
     import jax
@@ -353,19 +346,20 @@ def counters(state):
 
 def layer_metrics(reduced, counted=None, workers=None):
     """The per-layer numbers the name scopes and counters give, where they
-    are there to read: device time per step under ``worker_grads``,
-    ``attack`` and ``clip_norm`` (ms, per chip), the median duration of
+    are there to read: device time per step of the ops with
+    ``worker_grads``, ``attack`` or ``clip_norm`` on their path
+    (``within_s``, ms, per chip), the median duration of
     the difference-round modules (ms), and the share of the per-worker
     gradient evaluations Algorithm 1 needs (W per full round and two per
     sampled row of a difference round) among those ``counted`` over the
     window (%)."""
     out = {}
-    scope_s = reduced.get("scope_s", {}) if reduced else {}
+    within_s = reduced.get("within_s", {}) if reduced else {}
     for name, scope in (("fwd_bwd_ms.train", "worker_grads"),
                         ("attack_ms.train", "attack"),
                         ("clip_norm_ms.train", "clip_norm")):
-        if scope in scope_s:
-            out[name] = 1e3 * scope_s[scope] / reduced["steps"]
+        if scope in within_s:
+            out[name] = 1e3 * within_s[scope] / reduced["steps"]
     diff = [s for kind, s in (reduced or {}).get("modules", [])
             if kind == "diff"]
     if diff:

@@ -1,15 +1,16 @@
 """From a profiler trace to per-step device numbers.
 
-``load(xplane)`` keeps, per TPU device, the ops of its "XLA Ops" line and
-its "XLA Modules" line, and the harness's own host spans (``bench.step``
+``reduce`` takes, per TPU device, the ops of its "XLA Ops" line and its
+"XLA Modules" line, and the harness's own host spans (``bench.step``
 around the dispatch, ``bench.block`` around ``block_until_ready``,
-``bench.host`` around the loop's bookkeeping).  ``reduce`` turns them into
-busy time (the union of the intervals of ops that contain no other op),
-time per kernel name (a Pallas kernel's op is named by the kernel's
-``name=``), collective time and the part of it during which no other op
-runs, the ops with the most self time, and the longest idle gaps labelled
-with the host span they fall in.  Device and host clocks differ by an
-offset, taken as the least lead of a step's module over its dispatch.
+``bench.host`` around the loop's bookkeeping), as ``scoped.load`` reads
+them from a trace.  It turns them into busy time (the union of the
+intervals of ops that contain no other op), time per kernel name (a Pallas
+kernel's op is named by the kernel's ``name=``), collective time and the
+part of it during which no other op runs, and the ops with the most self
+time; ``scoped.reduce`` adds the name scopes and the idle gaps.  Device
+and host clocks differ by an offset, taken as the least lead of a step's
+module over its dispatch.
 """
 import re
 
@@ -27,31 +28,6 @@ def op_name(text: str) -> str:
 
 def kernel_of(name: str) -> str:
     return _SUFFIX.sub("", name)
-
-
-def load(path):
-    from jax.profiler import ProfileData
-
-    pd = ProfileData.from_file(str(path))
-    devices, host = {}, []
-    for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:"):
-            dev = devices.setdefault(plane.name, {"ops": [], "modules": []})
-            for line in plane.lines:
-                if line.name == "XLA Ops":
-                    dev["ops"] = [
-                        [op_name(ev.name), ev.start_ns,
-                         ev.start_ns + ev.duration_ns,
-                         "tpu_custom_call" in ev.name]
-                        for ev in line.events]
-                elif line.name == "XLA Modules":
-                    dev["modules"] = [[ev.start_ns, ev.start_ns + ev.duration_ns]
-                                      for ev in line.events]
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host += [[ev.name, ev.start_ns, ev.start_ns + ev.duration_ns]
-                         for ev in line.events if ev.name.startswith("bench.")]
-    return {"devices": devices, "host": sorted(host, key=lambda s: s[1])}
 
 
 def union(intervals):
@@ -131,7 +107,7 @@ def reduce(events, kernels=None):
     out = {"steps": len(steps), "window_s": (w1 - w0) * 1e-9,
            "devices": n_dev, "busy_s": 0.0, "kernel_s": {},
            "layer_s": {}, "collective_s": 0.0, "collective_exposed_s": 0.0}
-    top, gaps = {}, []
+    top = {}
     for dev in events["devices"].values():
         c = clock_offset(dev["modules"], steps)
         lo, hi = w0 + c, w1 + c
@@ -154,16 +130,9 @@ def reduce(events, kernels=None):
             if o[3]:
                 out["kernel_s"][name] = out["kernel_s"].get(name, 0.0) \
                     + s * 1e-9 / n_dev
-        idle = sorted(subtract([[lo, hi]], busy), key=lambda g: g[0] - g[1])
-        for a, b in idle[:10]:
-            mid = (a + b) / 2 - c
-            span = next((s[0] for s in events["host"]
-                         if s[1] <= mid < s[2]), "none")
-            gaps.append([span, (b - a) * 1e-9])
     for k, s in out["kernel_s"].items():
         layer = kernels.get(k, k)
         out["layer_s"][layer] = out["layer_s"].get(layer, 0.0) + s
     out["device_ops"] = sorted(([n, s] for n, s in top.items()),
                                key=lambda e: -e[1])[:10]
-    out["idle_gaps"] = sorted(gaps, key=lambda e: -e[1])[:10]
     return out

@@ -8,6 +8,7 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from chipbench import cells, counts  # noqa: E402
+from chipbench.reference import mamba2  # noqa: E402
 
 
 def test_mamba2_layer_by_hand():
@@ -20,7 +21,7 @@ def test_mamba2_layer_by_hand():
     # dot (2N) and a head-weighted x (2 di); inter-chunk: C.h and the
     # state update, 2 N di each
     ssd = (Q + 1) * (2 * N + 2 * di) // 2 + 4 * N * di
-    assert counts.mamba2_layer_flops(m, 2048) == \
+    assert mamba2.layer_flops(m, 2048) == \
         in_proj + out_proj + conv + ssd == 31_667_328
 
 

@@ -1,6 +1,7 @@
 """What the program records about itself, read from a trace: the clock
 bracket of the runtime's run_id events, idle gaps labelled by host event,
-name scopes and round kinds (``chipbench/scoped.py``), on two small traces
+name scopes, round kinds and the per-layer readers of them
+(``chipbench/scoped.py``, ``chipbench/metrics/``), on two small traces
 recorded on a TPU v5e chip and on synthetic events.  The recorded traces
 are each three steps under the harness's host spans: a jitted program
 holding one Pallas kernel (``coordinate_median``) and two XLA fusions, and
@@ -23,33 +24,28 @@ def events():
     return scoped.load(TRACE)
 
 
-def test_load_keeps_what_tracing_load_keeps(events):
-    """The same device ops, host spans and module intervals, each module
-    with its run_id."""
-    old = tracing.load(TRACE)
-    assert events["host"] == old["host"]
-    for name, dev in old["devices"].items():
-        got = events["devices"][name]
-        assert got["ops"] == dev["ops"]
-        assert [m[:2] for m in got["modules"]] == dev["modules"]
-
-
 def test_reduce_extends_tracing_reduce(events):
-    """Every number of ``tracing.reduce`` but the idle gaps' labels is kept
-    as it was; the gaps fall where the host was sleeping between steps,
-    after the runtime saw the module complete and before it enqueued the
-    next."""
+    """Every number of ``tracing.reduce`` is kept as it was; the idle gaps
+    are the window's longest stretches with no op on the device, and fall
+    where the host was sleeping between steps, after the runtime saw the
+    module complete and before it enqueued the next."""
     kernels = {"coordinate_median": "aggregation"}
-    old = tracing.reduce(tracing.load(TRACE), kernels=kernels)
+    old = tracing.reduce(events, kernels=kernels)
     red = scoped.reduce(events, kernels=kernels)
     for k, v in old.items():
-        if k != "idle_gaps":
-            assert red[k] == v, k
-    assert [g[1] for g in red["idle_gaps"]] == [g[1] for g in
-                                                old["idle_gaps"]]
-    assert old["idle_gaps"][0][0] == "bench.host"
+        assert red[k] == v, k
+    dev = events["devices"]["/device:TPU:0"]
+    steps = [s for s in events["host"] if s[0] == "bench.step"]
+    c = tracing.clock_offset(dev["modules"], steps)
+    blocks = [s for s in events["host"] if s[0] == "bench.block"]
+    lo, hi = steps[0][1] + c, blocks[-1][2] + c
+    idle = tracing.subtract([[lo, hi]], tracing.union(tracing.clip_to(
+        [[o[1], o[2]] for o in dev["ops"]], lo, hi)))
+    assert [g[1] for g in red["idle_gaps"]] == sorted(
+        ((b - a) * 1e-9 for a, b in idle), reverse=True)[:10]
     assert red["idle_gaps"][0][0] == "host:$time sleep"
     assert red["scope_s"] == {"unscoped": pytest.approx(red["busy_s"])}
+    assert red["within_s"] == {}
 
 
 def test_run_id_bracket_on_the_recorded_trace(events):
@@ -160,6 +156,11 @@ def test_scope_attribution_and_round_kinds():
     assert ns == {"worker_grads": 50, "attack": 15, "clip_norm": 2,
                   "aggregate": 13, "unscoped": 2}
     assert sum(red["scope_s"].values()) == pytest.approx(red["busy_s"])
+    # a scope takes the time of every op with it on its path: ``aggregate``
+    # holds ``clip_norm``, the rounds hold what ran in them
+    assert {k: round(v * 1e9) for k, v in red["within_s"].items()} == \
+        {"worker_grads": 50, "attack": 15, "clip_norm": 2, "aggregate": 15,
+         "round_diff": 30, "round_full": 10}
     assert {k: round(v * 1e9) for k, v in red["backward_s"].items()} == \
         {"worker_grads": 20}
     assert [(k, round(s * 1e9)) for k, s in red["modules"]] == \
@@ -167,6 +168,7 @@ def test_scope_attribution_and_round_kinds():
     # without the program's op names every op is unscoped
     bare = scoped.reduce(events, kernels={}, scopes=SCOPES)
     assert bare["scope_s"] == {"unscoped": pytest.approx(red["busy_s"])}
+    assert bare["within_s"] == {}
     assert [k for k, _ in bare["modules"]] == ["other", "other"]
 
 
@@ -229,6 +231,28 @@ def step():
                                  paths=scoped.load_op_paths(SCOPED_HLO))
 
 
+def test_a_nested_scope_leaves_within_s_as_it_was(step):
+    """A scope a later program names inside ``worker_grads`` (here, on
+    the rematerialized ops) takes their time from ``worker_grads`` in
+    ``scope_s``, which still sums to busy time, and leaves
+    ``within_s["worker_grads"]``, which the readers read, as it was."""
+    events, red = step
+    paths = {k: v.replace("/worker_grads/", "/worker_grads/ssm/")
+             if "checkpoint" in v else v
+             for k, v in scoped.load_op_paths(SCOPED_HLO).items()}
+    scopes = dict(scoped.scope_layers(), ssm="models/ssm.py")
+    nested = scoped.reduce(events, paths=paths, scopes=scopes)
+    assert nested["within_s"]["worker_grads"] == \
+        red["within_s"]["worker_grads"]
+    assert 0 < nested["scope_s"]["ssm"] == nested["within_s"]["ssm"] \
+        < red["scope_s"]["worker_grads"]
+    assert nested["scope_s"]["worker_grads"] + nested["scope_s"]["ssm"] \
+        == pytest.approx(red["scope_s"]["worker_grads"], rel=1e-12)
+    assert sum(nested["scope_s"].values()) == pytest.approx(
+        red["busy_s"], rel=1e-12)
+    assert scoped.layer_metrics(nested) == scoped.layer_metrics(red)
+
+
 def test_scoped_step_scopes_sum_to_busy_time(step):
     _, red = step
     assert red["steps"] == 3 and red["devices"] == 1
@@ -277,12 +301,16 @@ READ_BEFORE = {
 @pytest.mark.parametrize("trace,metric", [
     (t, m) for t in sorted(READ_BEFORE) for m in sorted(READ_BEFORE[t])])
 def test_existing_readers_read_as_before(events, step, trace, metric):
-    """Bit for bit, from ``tracing.reduce`` and from ``scoped.reduce``."""
+    """Bit for bit, from ``tracing.reduce`` and from ``scoped.reduce``,
+    with the program's op names as the harness gives them and without."""
     from chipbench import cells
 
     cell, config = cells.load_cell("mamba2_780m.full_w4_s2048")
     recorded = events if trace == "tiny_v5e" else step[0]
-    for reduced in (tracing.reduce(recorded), scoped.reduce(recorded)):
+    paths = scoped.load_op_paths(SCOPED_HLO) if trace == "tiny_step_v5e" \
+        else {}
+    for reduced in (tracing.reduce(recorded), scoped.reduce(recorded),
+                    scoped.reduce(recorded, paths=paths)):
         run = {"cell": cell, "config": config, "chips": 1,
                "device_kind": "TPU v5 lite", "trace": reduced}
         assert cells.read_metric(metric, run) == READ_BEFORE[trace][metric]
@@ -298,7 +326,7 @@ def test_layer_metrics_of_the_scoped_step(step):
     for name, scope in (("fwd_bwd_ms.train", "worker_grads"),
                         ("attack_ms.train", "attack"),
                         ("clip_norm_ms.train", "clip_norm")):
-        assert got[name] == 1e3 * red["scope_s"][scope] / 3
+        assert got[name] == 1e3 * red["within_s"][scope] / 3
     diff = sorted(s for k, s in red["modules"] if k == "diff")
     assert got["diff_round_ms.train"] == 1e3 * (diff[0] + diff[1]) / 2
     assert "useful_eval_share.train" not in got
@@ -308,3 +336,36 @@ def test_layer_metrics_of_the_scoped_step(step):
     share = scoped.layer_metrics(red, counted, workers=4)
     assert share["useful_eval_share.train"] == 100.0 * 232 / 784
     assert scoped.layer_metrics(None) == {}
+
+
+# the per-layer readers of the name scopes and counters on the recorded
+# step, with the counters of its three window rounds (difference, full,
+# Byzantine-only difference; W = 4, C = 1)
+TINY_COUNTED = {"rounds_full": 1, "rows_sampled": 1 + 4 + 1,
+                "rounds_byzantine_only": 1, "rows_clipped": 0,
+                "worker_evals": 4 * (2 * 3 - 1)}
+READ_SCOPED = {
+    "fwd_bwd_ms.train": 0.23396800000000004,
+    "attack_ms.train": 0.004729666666666667,
+    "clip_norm_ms.train": 0.007412666666666668,
+    "diff_round_ms.train": 0.3786195,
+    "useful_eval_share.train": 100.0 * (4 + 2 * 2) / 20,
+}
+
+
+@pytest.mark.parametrize("metric", sorted(READ_SCOPED))
+def test_scope_readers_on_the_scoped_step(step, metric):
+    """Each reader gives ``layer_metrics``' number, as recorded; without
+    the program's op names or counters it reads nothing."""
+    from chipbench import cells
+
+    cell, config = cells.load_cell("mamba2_780m.pp_w4c1_s512")
+    events, red = step
+    run = {"cell": cell, "config": config, "chips": 1,
+           "device_kind": "TPU v5 lite", "trace": red,
+           "counters": TINY_COUNTED}
+    got = cells.read_metric(metric, run)
+    assert got == READ_SCOPED[metric]
+    assert got == scoped.layer_metrics(red, TINY_COUNTED, 4)[metric]
+    bare = dict(run, trace=scoped.reduce(events), counters=None)
+    assert cells.read_metric(metric, bare) is None

@@ -1,7 +1,7 @@
 """The trace reduction, on a small trace recorded on a TPU v5e chip: three
 steps of a jitted program holding one Pallas kernel (``coordinate_median``
 over a (4, 131072) bf16 matrix) and two XLA fusions, under the harness's
-host spans."""
+host spans, read as the harness reads it (``scoped.load``)."""
 from pathlib import Path
 
 import pytest
@@ -11,7 +11,7 @@ import sys
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from chipbench import tracing  # noqa: E402
+from chipbench import scoped, tracing  # noqa: E402
 
 TRACE = ROOT / "chipbench" / "testdata" / \
     "tiny_v5e.xplane.pb"
@@ -21,7 +21,7 @@ KERNEL_NS = (73250, 73831, 73143)
 
 @pytest.fixture(scope="module")
 def events():
-    return tracing.load(TRACE)
+    return scoped.load(TRACE)
 
 
 def test_load_keeps_device_ops_modules_and_host_spans(events):
@@ -38,7 +38,7 @@ def test_load_keeps_device_ops_modules_and_host_spans(events):
 
 
 def test_reduce_kernel_sums_busy_and_idle(events):
-    red = tracing.reduce(events, kernels={"coordinate_median": "aggregation"})
+    red = scoped.reduce(events, kernels={"coordinate_median": "aggregation"})
     assert red["steps"] == 3 and red["devices"] == 1
     assert red["kernel_s"]["coordinate_median"] == pytest.approx(
         sum(KERNEL_NS) * 1e-9)
@@ -54,7 +54,7 @@ def test_reduce_kernel_sums_busy_and_idle(events):
     assert red["collective_s"] == 0 and red["collective_exposed_s"] == 0
     assert red["device_ops"][0][0] == "coordinate_median"
     # the gaps fall where the host was sleeping between steps
-    assert red["idle_gaps"][0][0] == "bench.host"
+    assert red["idle_gaps"][0][0] == "host:$time sleep"
 
 
 def test_clock_offset_takes_least_lead(events):
